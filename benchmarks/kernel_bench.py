@@ -55,6 +55,7 @@ from repro.kernels.autotune import (KERNELS, autotune, build_cost_table,
                                     grid_steps, kernel_bytes_moved,
                                     modeled_time_s)
 from repro.kernels.fused_agg import fused_agg
+from repro.kernels.ops import interpret_mode
 from repro.kernels.pair_fuse import pair_fuse
 from repro.kernels.quant_agg import quant_agg
 from repro.launch.roofline import bandwidth_time_s
@@ -119,11 +120,13 @@ def _inputs(kernel: str, k: int, n: int):
 
 def _call(kernel: str, bn: int, kb: int):
     if kernel == "fused_agg":
-        return lambda u, w: fused_agg(u, w, bn=bn, kb=kb, interpret=True)
+        return lambda u, w: fused_agg(u, w, bn=bn, kb=kb,
+                                      interpret=interpret_mode())
     if kernel == "quant_agg":
-        return lambda q, s: quant_agg(q, s, bn=bn, kb=kb, interpret=True)
+        return lambda q, s: quant_agg(q, s, bn=bn, kb=kb,
+                                      interpret=interpret_mode())
     return lambda a, b: pair_fuse(a, b, op="wsum", wa=0.5, wb=0.5,
-                                  bn=bn, interpret=True)
+                                  bn=bn, interpret=interpret_mode())
 
 
 def _ref_call(kernel: str):
@@ -169,17 +172,35 @@ def measured_rows() -> List[Dict]:
         args = _inputs(kernel, k, n)
         tuned = autotune(kernel, k, n)
         us_ref = timeit(_ref_call(kernel), *args)
-        us_def = timeit(_call(kernel, spec.default_bn, spec.default_kb),
-                        *args)
-        us_tun = timeit(_call(kernel, tuned.bn, tuned.kb), *args)
+        us_def, us_tun, speedup = _paired(
+            _call(kernel, spec.default_bn, spec.default_kb),
+            _call(kernel, tuned.bn, tuned.kb), *args)
         rows.append({
             "kernel": kernel, "k": k, "n": n,
             "us_ref_cpu": round(us_ref, 1),
             "us_default": round(us_def, 1),
             "us_tuned": round(us_tun, 1),
-            "measured_speedup": round(us_def / us_tun, 2),
+            "measured_speedup": round(speedup, 2),
         })
     return rows
+
+
+def _paired(f_def, f_tun, *args, trials: int = 5):
+    """(median us of f_def, median us of f_tun, median per-trial ratio).
+
+    Each trial times the two back to back, so load from other processes
+    that comes and goes within a run weighs on both sides of the ratio."""
+    jax.block_until_ready(f_def(*args))  # warmup: compile AND drain
+    jax.block_until_ready(f_tun(*args))
+    t_def, t_tun = [], []
+    for _ in range(trials):
+        for fn, ts in ((f_def, t_def), (f_tun, t_tun)):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            ts.append(time.perf_counter() - t0)
+    ratio = np.median(np.asarray(t_def) / np.asarray(t_tun))
+    return (float(np.median(t_def)) * 1e6, float(np.median(t_tun)) * 1e6,
+            float(ratio))
 
 
 def speedups(measured: List[Dict]) -> Dict[str, float]:

@@ -16,6 +16,9 @@ def _section(title):
 
 
 def main() -> None:
+    from repro import compile_cache
+
+    compile_cache.enable()
     args = sys.argv[1:]
     only = None
     if "--only" in args:
@@ -93,12 +96,16 @@ def main() -> None:
 
     if only in (None, "dist_agg"):
         _section("distributed aggregation on the 16x16 mesh (t_agg roofline)")
-        # subprocess: needs 512 host devices, the rest of the suite needs 1
+        # subprocess: needs 512 host devices, the rest of the suite needs 1.
+        # It is a host-device lowering study, forced onto the CPU: this
+        # process may already hold the accelerator.
+        import os
         import subprocess
 
         r = subprocess.run(
             [sys.executable, "-m", "benchmarks.dist_agg"],
             capture_output=True, text=True, timeout=1200,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         print(r.stdout, end="")
         if r.returncode != 0:

@@ -16,7 +16,7 @@ prices ALL strategies from one shared run).
 """
 import argparse
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.api import Platform
 from repro.core import STRATEGIES
 from repro.core.jobspec import FLJobSpec, PartySpec
@@ -74,4 +74,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

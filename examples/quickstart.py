@@ -11,7 +11,7 @@ the paper's three execution vehicles:
 """
 import numpy as np
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.api import Platform, replay_measured
 from repro.core import (AggregationEstimator, PolicyConfig, STRATEGIES,
                         savings)
@@ -103,4 +103,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.core.jobspec import FLJobSpec, PartySpec
 from repro.core.prediction import UpdatePredictor
 from repro.kernels import fuse_quantized, fuse_updates, quantize_update
@@ -73,4 +73,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -399,7 +399,7 @@ class Platform:
         simulation policy.
 
         `runtime_kw` is forwarded to ``repro.fl.job.FLJobRuntime``
-        (n_sequences, heterogeneous, seed, epochs_per_round, interpret, ...).
+        (n_sequences, heterogeneous, seed, epochs_per_round, ...).
         The platform's cluster config prices the virtual timeline. The
         estimator: ``runtime_kw["estimator"]`` if given, else a copy of the
         platform's when the platform was built with an explicit one (the

@@ -29,7 +29,6 @@ class AggregationExecutor:
         queue: Optional[MessageQueue] = None,
         *,
         n_workers: int = 1,
-        interpret: bool = True,
         group: str = "aggregator",
     ):
         self.job_id = job_id
@@ -37,7 +36,6 @@ class AggregationExecutor:
                     if isinstance(algorithm, str) else algorithm)
         self.queue = queue or MessageQueue()
         self.n_workers = max(1, n_workers)
-        self.interpret = interpret
         self.group = group
         self.state = FusionState()
 
@@ -52,9 +50,7 @@ class AggregationExecutor:
                 topic.commit(self.group, m.offset)  # stale round: drop
                 continue
             w = self.alg.weight_of(m.value.get("n_examples", 1))
-            self.state = self.state.fold(
-                m.value["update"], w, interpret=self.interpret
-            )
+            self.state = self.state.fold(m.value["update"], w)
             topic.commit(self.group, m.offset)
             n += 1
         return n
@@ -100,18 +96,18 @@ class AggregationExecutor:
         if self.n_workers == 1:
             st = FusionState()
             for u, w in zip(updates, ws):
-                st = st.fold(u, w, interpret=self.interpret)
+                st = st.fold(u, w)
         else:
             partials: List[FusionState] = []
             for s in range(self.n_workers):
                 p = FusionState()
                 for u, w in list(zip(updates, ws))[s::self.n_workers]:
-                    p = p.fold(u, w, interpret=self.interpret)
+                    p = p.fold(u, w)
                 if p.acc is not None:
                     partials.append(p)
             st = partials[0]
             for p in partials[1:]:
-                st = st.merge(p, interpret=self.interpret)
+                st = st.merge(p)
         fused = st.result()
         if global_model is None:
             return fused

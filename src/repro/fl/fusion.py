@@ -31,23 +31,21 @@ class FusionState:
     total_weight: float = 0.0
     n_fused: int = 0
 
-    def fold(self, update: Pytree, weight: float, *, interpret: bool = True
-             ) -> "FusionState":
+    def fold(self, update: Pytree, weight: float) -> "FusionState":
         return FusionState(
-            acc=accumulate(self.acc, update, weight, interpret=interpret),
+            acc=accumulate(self.acc, update, weight),
             total_weight=self.total_weight + weight,
             n_fused=self.n_fused + 1,
         )
 
-    def merge(self, other: "FusionState", *, interpret: bool = True
-              ) -> "FusionState":
+    def merge(self, other: "FusionState") -> "FusionState":
         """Merge two partial aggregates (parallel aggregation)."""
         if self.acc is None:
             return other
         if other.acc is None:
             return self
         return FusionState(
-            acc=accumulate(self.acc, other.acc, 1.0, interpret=interpret),
+            acc=accumulate(self.acc, other.acc, 1.0),
             total_weight=self.total_weight + other.total_weight,
             n_fused=self.n_fused + other.n_fused,
         )
@@ -67,12 +65,11 @@ class FusionAlgorithm:
     def weight_of(self, n_examples: int) -> float:
         return float(max(n_examples, 1))
 
-    def fuse(self, updates: Sequence[Pytree], n_examples: Sequence[int],
-             *, interpret: bool = True) -> Pytree:
+    def fuse(self, updates: Sequence[Pytree], n_examples: Sequence[int]
+             ) -> Pytree:
         ws = [self.weight_of(n) for n in n_examples]
         total = sum(ws)
-        return fuse_updates(updates, [w / total for w in ws],
-                            interpret=interpret)
+        return fuse_updates(updates, [w / total for w in ws])
 
     def apply(self, global_model: Pytree, fused: Pytree, lr: float = 1.0
               ) -> Pytree:

@@ -39,6 +39,7 @@ from repro.data.partition import dirichlet_domain_mixes, party_sizes
 from repro.data.synthetic import SyntheticLM, SyntheticLMConfig
 from repro.fl.aggregator import AggregationExecutor
 from repro.fl.party import Party
+from repro.kernels import accumulate
 from repro.models import model as M
 
 Pytree = Any
@@ -69,7 +70,6 @@ class FLJobRuntime:
         eval_sequences: int = 64,
         seed: int = 0,
         epochs_per_round: int = 1,
-        interpret: bool = True,
         cluster_config: Optional[ClusterConfig] = None,
         estimator: Optional[AggregationEstimator] = None,
     ):
@@ -80,7 +80,6 @@ class FLJobRuntime:
         self.queue = MessageQueue()
         self.agg = AggregationExecutor(
             spec.job_id, spec.aggregation_algorithm, self.queue,
-            interpret=interpret,
         )
         # ---- data ---------------------------------------------------------
         data_cfg = SyntheticLMConfig(
@@ -115,7 +114,7 @@ class FLJobRuntime:
             eval_sequences, seed=seed + 10_000,
         )
         # ---- scheduling machinery -------------------------------------------
-        self.estimator = estimator or self._make_estimator(interpret)
+        self.estimator = estimator or self._make_estimator()
         self.t_pair0 = self.estimator.t_pair_s  # pre-calibration t_pair
         self.cluster_cfg = cluster_config or ClusterConfig()
         # virtual replay: a RoundEngine on a private simulated cluster, fed
@@ -138,14 +137,11 @@ class FLJobRuntime:
         self.records: List[RoundRecord] = []
         self.measured_rounds: List[Dict[str, Tuple[float, float]]] = []
 
-    def _make_estimator(self, interpret: bool) -> AggregationEstimator:
+    def _make_estimator(self) -> AggregationEstimator:
         """Offline t_pair measurement on the actual fusion kernel (§5.4)."""
-        from repro.kernels.pair_fuse import pair_fuse
-
         model_bytes = self.spec.model_bytes
         t_pair = measure_t_pair(
-            lambda a, b: pair_fuse(jnp.asarray(a), jnp.asarray(b), op="wsum",
-                                   wa=1.0, wb=1.0, interpret=interpret),
+            lambda a, b: accumulate(jnp.asarray(a), jnp.asarray(b), 1.0),
             min(model_bytes, 4 << 20),  # cap the probe size on CPU
         )
         # scale to the true model size (fusion is linear in bytes)

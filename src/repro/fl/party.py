@@ -4,6 +4,7 @@ parties to report (epoch time, minibatch time, dataset size)."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, Optional, Tuple
 
@@ -28,6 +29,43 @@ class LocalResult:
     loss: float
 
 
+@functools.lru_cache(maxsize=None)
+def _local_steps(cfg: ModelConfig, algorithm: str, lr: float,
+                 prox_mu: float) -> Tuple[Any, Any, Any]:
+    """(optimizer, jitted train step, jitted gradient step), shared by every
+    party of a job: the parties agree on the model and hyperparameters, so
+    the step compiles once per process, not once per party."""
+    opt = sgd(lr)
+
+    def party_loss(params, batch, global_params):
+        loss, metrics = M.loss_fn(cfg, params, batch)
+        if algorithm == "fedprox" and prox_mu > 0:
+            # FedProx: + mu/2 * ||w - w_global||^2 on the PARTY objective
+            sq = sum(
+                jnp.sum(jnp.square(p.astype(jnp.float32) -
+                                   g.astype(jnp.float32)))
+                for p, g in zip(jax.tree.leaves(params),
+                                jax.tree.leaves(global_params))
+            )
+            loss = loss + 0.5 * prox_mu * sq
+        return loss, metrics
+
+    def step(params, opt_state, batch, global_params):
+        (loss, _), grads = jax.value_and_grad(
+            party_loss, has_aux=True
+        )(params, batch, global_params)
+        params, opt_state = opt.update(grads, opt_state, params)
+        return params, opt_state, loss
+
+    def gstep(params, batch):
+        (loss, _), grads = jax.value_and_grad(
+            lambda p: M.loss_fn(cfg, p, batch), has_aux=True
+        )(params)
+        return grads, loss
+
+    return opt, jax.jit(step), jax.jit(gstep)
+
+
 class Party:
     def __init__(
         self,
@@ -48,58 +86,28 @@ class Party:
         self.n_examples = self.loader.n
         self.lr = lr
         self.prox_mu = prox_mu
-        self._opt = sgd(lr)
-        self._step = jax.jit(self._make_step())
-        self._grad_accum = jax.jit(self._make_grad())
 
-    # ---- compiled steps -------------------------------------------------------
-    def _loss(self, params, batch, global_params):
-        loss, metrics = M.loss_fn(self.cfg, params, batch)
-        if self.algorithm == "fedprox" and self.prox_mu > 0:
-            # FedProx: + mu/2 * ||w - w_global||^2 on the PARTY objective
-            sq = sum(
-                jnp.sum(jnp.square(p.astype(jnp.float32) -
-                                   g.astype(jnp.float32)))
-                for p, g in zip(jax.tree.leaves(params),
-                                jax.tree.leaves(global_params))
-            )
-            loss = loss + 0.5 * self.prox_mu * sq
-        return loss, metrics
-
-    def _make_step(self):
-        def step(params, opt_state, batch, global_params):
-            (loss, _), grads = jax.value_and_grad(
-                self._loss, has_aux=True
-            )(params, batch, global_params)
-            params, opt_state = self._opt.update(grads, opt_state, params)
-            return params, opt_state, loss
-
-        return step
-
-    def _make_grad(self):
-        def gstep(params, batch):
-            (loss, _), grads = jax.value_and_grad(
-                lambda p: M.loss_fn(self.cfg, p, batch), has_aux=True
-            )(params)
-            return grads, loss
-
-        return gstep
+    def _steps(self):
+        """(optimizer, train step, gradient step) for the current settings."""
+        return _local_steps(self.cfg, self.algorithm, float(self.lr),
+                            float(self.prox_mu))
 
     # ---- §5.2 timing report: measure one minibatch (post-compilation) ---------
     def calibrate(self, global_params: Pytree) -> Tuple[float, float]:
         """Returns (minibatch_time_s, epoch_time_s estimate)."""
+        opt, step, grad = self._steps()
         batch = _to_jnp(next(self.loader.epoch(shuffle=False)))
-        opt_state = self._opt.init(global_params)
+        opt_state = opt.init(global_params)
         # warmup (compile)
         if self.algorithm == "fedsgd":
-            self._grad_accum(global_params, batch)
+            grad(global_params, batch)
             t0 = time.perf_counter()
-            jax.block_until_ready(self._grad_accum(global_params, batch))
+            jax.block_until_ready(grad(global_params, batch))
         else:
-            self._step(global_params, opt_state, batch, global_params)
+            step(global_params, opt_state, batch, global_params)
             t0 = time.perf_counter()
             jax.block_until_ready(
-                self._step(global_params, opt_state, batch, global_params)
+                step(global_params, opt_state, batch, global_params)
             )
         t_mb = time.perf_counter() - t0
         return t_mb, t_mb * len(self.loader)
@@ -107,6 +115,7 @@ class Party:
     # ---- one FL round of local work ----------------------------------------------
     def local_round(self, global_params: Pytree, epochs: int = 1
                     ) -> LocalResult:
+        opt, step, grad = self._steps()
         t0 = time.perf_counter()
         n_batches = 0
         last_loss = 0.0
@@ -114,7 +123,7 @@ class Party:
             # one pass, average gradients (classic FedSGD)
             acc = None
             for batch in self.loader.epoch():
-                grads, loss = self._grad_accum(global_params, _to_jnp(batch))
+                grads, loss = grad(global_params, _to_jnp(batch))
                 acc = grads if acc is None else jax.tree.map(
                     jnp.add, acc, grads
                 )
@@ -123,10 +132,10 @@ class Party:
             update = jax.tree.map(lambda g: g / n_batches, acc)
         else:
             params = global_params
-            opt_state = self._opt.init(params)
+            opt_state = opt.init(params)
             for _ in range(epochs):
                 for batch in self.loader.epoch():
-                    params, opt_state, loss = self._step(
+                    params, opt_state, loss = step(
                         params, opt_state, _to_jnp(batch), global_params
                     )
                     n_batches += 1

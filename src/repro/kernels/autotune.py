@@ -30,10 +30,11 @@ simulator prices fuse work with:
   * ``basis="roofline"`` (the CPU container default) projects t_pair from
     the bandwidth roofline at the tuned tile — what the kernel would cost
     on the target TPU. This is honest about what a CPU box can know.
-  * ``basis="measured"`` additionally wall-clocks the tuned kernel
-    (``interpret=False``; run this ON the TPU target) and records the
-    measured median instead. The artifact records its basis so a consumer
-    can tell projection from measurement.
+  * ``basis="measured"`` additionally wall-clocks the compiled tuned kernel
+    and records the measured median instead. It runs only on a TPU (it
+    fails elsewhere) and stamps the table with the chip's ``device_kind``.
+    The artifact records its basis so a consumer can tell projection from
+    measurement.
 
 ``AggregationEstimator(cost_table=...)`` (and ``Platform(cost_table=...)``)
 then source simulated t_pair/t_agg from the table instead of a config
@@ -298,12 +299,9 @@ class KernelCostTable:
 
 
 def _measure_pair_s(kernel: str, n_elems: int, bn: int, kb: int, *,
-                    interpret: bool, trials: int = 3) -> float:
-    """Median wall-clock of one tuned kernel launch, warmup blocked.
-
-    With ``interpret=False`` on a real TPU this IS the measured t_pair;
-    interpret mode executes the kernel body per grid step in Python and is
-    only useful as a plumbing check."""
+                    trials: int = 3) -> float:
+    """Median wall-clock of one compiled tuned kernel launch, warmup
+    blocked: on a TPU this IS the measured t_pair."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -316,16 +314,16 @@ def _measure_pair_s(kernel: str, n_elems: int, bn: int, kb: int, *,
     if kernel == "pair_fuse":
         a = jax.random.normal(key, (n_elems,), jnp.float32)
         fn = lambda: pair_fuse(a, a, op="wsum", wa=0.5, wb=0.5,
-                               bn=bn, interpret=interpret)
+                               bn=bn, interpret=False)
     elif kernel == "fused_agg":
         u = jax.random.normal(key, (kb, n_elems), jnp.float32)
         w = jnp.full((kb,), 1.0 / kb, jnp.float32)
-        fn = lambda: fused_agg(u, w, bn=bn, kb=kb, interpret=interpret)
+        fn = lambda: fused_agg(u, w, bn=bn, kb=kb, interpret=False)
     elif kernel == "quant_agg":
         q = jax.random.randint(key, (kb, n_elems), -127, 128,
                                dtype=jnp.int8)
         s = jnp.full((kb,), 0.01, jnp.float32)
-        fn = lambda: quant_agg(q, s, bn=bn, kb=kb, interpret=interpret)
+        fn = lambda: quant_agg(q, s, bn=bn, kb=kb, interpret=False)
     else:
         raise ValueError(kernel)
     jax.block_until_ready(fn())  # warmup: compile AND finish async work
@@ -352,11 +350,21 @@ def build_cost_table(
     """Tune every (kernel, model size) and emit the cost-table artifact.
 
     ``basis="roofline"`` projects t_pair from the tuned tile's bandwidth
-    roofline (what a CPU container can honestly say about the TPU target);
-    ``basis="measured"`` wall-clocks the tuned kernel with
-    ``interpret=False`` — run it on the TPU host and ship the JSON.
+    roofline (what a CPU container can honestly say about the TPU target)
+    and names the table ``hw_name``; ``basis="measured"`` wall-clocks the
+    compiled tuned kernel on the TPU it runs on, fails without one, and
+    names the table by that chip's ``device_kind``.
     """
     assert basis in ("roofline", "measured"), basis
+    if basis == "measured":
+        import jax
+
+        dev = jax.devices()[0]
+        if dev.platform != "tpu":
+            raise RuntimeError(
+                f"basis='measured' times the compiled kernels on a TPU; "
+                f"this process runs on {dev.platform!r}")
+        hw_name = dev.device_kind
     entries: List[CostEntry] = []
     for kernel in kernels:
         spec = KERNELS[kernel]
@@ -365,8 +373,7 @@ def build_cost_table(
             k = spec.default_kb if kernel != "pair_fuse" else 2
             choice = autotune(kernel, k, n, hw=hw)
             if basis == "measured":
-                t_pair = _measure_pair_s(kernel, n, choice.bn, choice.kb,
-                                         interpret=False)
+                t_pair = _measure_pair_s(kernel, n, choice.bn, choice.kb)
             else:
                 # per-pair share of one modeled launch at the tuned tile
                 pairs = max(k - 1, 1) if kernel != "pair_fuse" else 1
@@ -386,7 +393,7 @@ def main() -> None:
     ap.add_argument("--basis", choices=("roofline", "measured"),
                     default="roofline",
                     help="roofline: project from the tuned tile (CPU-safe);"
-                         " measured: wall-clock interpret=False on a TPU")
+                         " measured: wall-clock the compiled kernels on a TPU")
     ap.add_argument("--out", default="kernel_cost_table.json")
     args = ap.parse_args()
     sizes = [int(float(s) * (1 << 20))

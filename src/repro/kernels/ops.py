@@ -2,9 +2,9 @@
 PYTREES (the paper's "list of one-dimensional vectors, one per layer").
 
 All entry points accept/return pytrees of arrays; leaves are flattened,
-fused leaf-wise by the kernels, and reshaped back. `interpret=True` executes
-the Pallas kernel bodies in Python on CPU (the validation mode for this
-container); on a real TPU pass interpret=False.
+fused leaf-wise by the kernels, and reshaped back. The kernel mode is decided
+here, once, from the backend (`interpret_mode`): on the CPU backend the
+Pallas kernel bodies run in the interpreter, on a TPU they are compiled.
 """
 from __future__ import annotations
 
@@ -21,8 +21,10 @@ from repro.kernels.quant_agg import quant_agg, quantize
 Pytree = Any
 
 
-def _leaves(tree: Pytree):
-    return jax.tree.leaves(tree)
+def interpret_mode() -> bool:
+    """Run the Pallas kernels interpreted only on the CPU backend; on any
+    accelerator they compile (there is no fallback to the interpreter)."""
+    return jax.default_backend() == "cpu"
 
 
 def _tile_kwargs(bn: Optional[int], kb: Optional[int] = None) -> dict:
@@ -39,7 +41,6 @@ def fuse_updates(
     updates: Sequence[Pytree],
     weights: Optional[Sequence[float]] = None,
     *,
-    interpret: bool = True,
     bn: Optional[int] = None,
     kb: Optional[int] = None,
 ) -> Pytree:
@@ -57,7 +58,7 @@ def fuse_updates(
     fused = []
     for i in range(len(leaves[0])):
         stack = jnp.stack([l[i].reshape(-1) for l in leaves])  # (K, N)
-        out = fused_agg(stack, w, interpret=interpret,
+        out = fused_agg(stack, w, interpret=interpret_mode(),
                         **_tile_kwargs(bn, kb))
         fused.append(out.reshape(leaves[0][i].shape).astype(leaves[0][i].dtype))
     return jax.tree.unflatten(treedef, fused)
@@ -68,7 +69,6 @@ def accumulate(
     update: Pytree,
     weight: float,
     *,
-    interpret: bool = True,
     bn: Optional[int] = None,
 ) -> Pytree:
     """Streaming (incremental) fusion: acc <- acc + weight*update.
@@ -83,7 +83,8 @@ def accumulate(
     return jax.tree.map(
         lambda a, u: pair_fuse(
             a.reshape(-1), u.astype(jnp.float32).reshape(-1),
-            op="wsum", wa=1.0, wb=float(weight), interpret=interpret,
+            op="wsum", wa=1.0, wb=float(weight),
+            interpret=interpret_mode(),
             **_tile_kwargs(bn),
         ).reshape(a.shape),
         acc,
@@ -96,7 +97,6 @@ def fuse_quantized(
     scales: Sequence[Pytree],
     weights: Optional[Sequence[float]] = None,
     *,
-    interpret: bool = True,
     bn: Optional[int] = None,
     kb: Optional[int] = None,
 ) -> Pytree:
@@ -116,7 +116,7 @@ def fuse_quantized(
         sc = jnp.asarray(
             [float(ss[j][i]) * weights[j] for j in range(k)], jnp.float32
         )
-        out = quant_agg(stack, sc, interpret=interpret,
+        out = quant_agg(stack, sc, interpret=interpret_mode(),
                         **_tile_kwargs(bn, kb))
         fused.append(out.reshape(qs[0][i].shape))
     return jax.tree.unflatten(treedef, fused)

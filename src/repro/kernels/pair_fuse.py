@@ -4,7 +4,8 @@
 
 used by incremental (streaming / eager) aggregation, where updates are fused
 one pair at a time as they arrive. f is selected statically: mean, weighted
-sum, max, min. Elementwise and bandwidth-bound; (8, 1024) fp32 tiles.
+sum, max, min. Elementwise and bandwidth-bound; (8, 1024) fp32 tiles. The
+two weights are scalars held in SMEM.
 
 The block size ``bn`` is tunable (multiple of 1024 = 8*128 fp32 lanes);
 `repro.kernels.autotune` picks it per model size by minimising modeled HBM
@@ -18,19 +19,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BN = 8 * 1024
 BN = DEFAULT_BN  # backwards-compatible alias
 
 
 def _make_kernel(op: str):
-    def kernel(wa_ref, wb_ref, a_ref, b_ref, o_ref):
+    def kernel(w_ref, a_ref, b_ref, o_ref):
         a = a_ref[...].astype(jnp.float32)
         b = b_ref[...].astype(jnp.float32)
         if op == "mean":
             o = 0.5 * (a + b)
         elif op == "wsum":
-            o = wa_ref[0] * a + wb_ref[0] * b
+            o = w_ref[0] * a + w_ref[1] * b
         elif op == "max":
             o = jnp.maximum(a, b)
         elif op == "min":
@@ -51,26 +53,21 @@ def pair_fuse(
     wa: float = 0.5,
     wb: float = 0.5,
     bn: int = DEFAULT_BN,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     (n,) = a.shape
-    np_ = -(-n // bn) * bn
-    if np_ != n:
-        a = jnp.pad(a, (0, np_ - n))
-        b = jnp.pad(b, (0, np_ - n))
-    wa_arr = jnp.full((1,), wa, jnp.float32)
-    wb_arr = jnp.full((1,), wb, jnp.float32)
-    out = pl.pallas_call(
+    bn = min(bn, n)  # see weighted_sum: a short vector is one whole block
+    w = jnp.asarray([wa, wb], jnp.float32)
+    # ragged last block: no padded copies of the operands
+    return pl.pallas_call(
         _make_kernel(op),
-        grid=(np_ // bn,),
+        grid=(pl.cdiv(n, bn),),
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bn,), lambda i: (i,)),
             pl.BlockSpec((bn,), lambda i: (i,)),
         ],
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), a.dtype),
+        out_shape=jax.ShapeDtypeStruct((n,), a.dtype),
         interpret=interpret,
-    )(wa_arr, wb_arr, a, b)
-    return out[:n]
+    )(w, a, b)

@@ -6,60 +6,30 @@ fp32 updates in HBM:
 
   out[n] = sum_k scale[k] * q[k, n]
 
-Same accumulation-grid structure as fused_agg; int8 tiles are (32, 128), so
-BN stays a multiple of 1024 and KB a multiple of 32 for alignment.
+This is fused_agg's weighted-sum kernel with the scales as weights and an
+fp32 result; int8 tiles are (32, 128), so BN stays a multiple of 1024 and
+KB a multiple of 32 for alignment.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels.fused_agg import weighted_sum
 
 DEFAULT_BN = 4096
 DEFAULT_KB = 32
 
 
-def _kernel(s_ref, q_ref, o_ref):
-    k = pl.program_id(0)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    q = q_ref[...].astype(jnp.float32)  # (KB, BN)
-    s = s_ref[...]  # (KB,) fp32
-    o_ref[...] += jnp.einsum("k,kn->n", s, q)
-
-
-@functools.partial(jax.jit, static_argnames=("bn", "kb", "interpret"))
 def quant_agg(
     q: jax.Array,  # (K, N) int8
     scales: jax.Array,  # (K,) fp32
     *,
     bn: int = DEFAULT_BN,
     kb: int = DEFAULT_KB,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    k, n = q.shape
-    kp = -(-k // kb) * kb
-    np_ = -(-n // bn) * bn
-    if kp != k or np_ != n:
-        q = jnp.pad(q, ((0, kp - k), (0, np_ - n)))
-        scales = jnp.pad(scales, (0, kp - k))
-    out = pl.pallas_call(
-        _kernel,
-        grid=(kp // kb, np_ // bn),
-        in_specs=[
-            pl.BlockSpec((kb,), lambda i, j: (i,)),
-            pl.BlockSpec((kb, bn), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bn,), lambda i, j: (j,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.float32),
-        interpret=interpret,
-    )(scales, q)
-    return out[:n]
+    return weighted_sum(q, scales, bn=bn, kb=kb, interpret=interpret)
 
 
 def quantize(x: jax.Array) -> tuple[jax.Array, jax.Array]:

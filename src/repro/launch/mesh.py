@@ -26,15 +26,23 @@ class HardwareSpec:
 V5E = HardwareSpec()
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the sharding rules place arrays
+    with ``with_sharding_constraint``, which refuses ``Explicit`` axes (the
+    default of ``jax.make_mesh``)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2):
-    """Small mesh over forced host devices, for tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Small mesh over the local devices (forced host devices in tests)."""
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def n_chips(mesh) -> int:

@@ -41,7 +41,7 @@ def main(argv=None):
     from repro.configs.base import INPUT_SHAPES, InputShape
     from repro.launch import sharding as shd
     from repro.launch import steps as steps_mod
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_host_mesh, make_production_mesh
     from repro.models import model as M
     from repro.models.sharding_ctx import activation_sharding
     from repro.models.spec import init_params
@@ -50,7 +50,7 @@ def main(argv=None):
     cfg = configs.get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_host_mesh(1, 1)
         shape = InputShape("smoke", args.seq_len, args.batch, "train")
     else:
         mesh = make_production_mesh()

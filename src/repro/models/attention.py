@@ -28,10 +28,13 @@ NEG_INF = -1e30
 def attn_specs(cfg: ModelConfig, cross: bool = False) -> Dict[str, TensorSpec]:
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     s: Dict[str, TensorSpec] = {
-        "wq": TensorSpec((d, h, hd), ("d_model", "heads", None)),
-        "wk": TensorSpec((d, kv, hd), ("d_model", "kv_heads", None)),
-        "wv": TensorSpec((d, kv, hd), ("d_model", "kv_heads", None)),
-        "wo": TensorSpec((h, hd, d), ("heads", None, "d_model")),
+        "wq": TensorSpec((d, h, hd), ("d_model", "heads", None), fan_in=d),
+        "wk": TensorSpec((d, kv, hd), ("d_model", "kv_heads", None),
+                         fan_in=d),
+        "wv": TensorSpec((d, kv, hd), ("d_model", "kv_heads", None),
+                         fan_in=d),
+        "wo": TensorSpec((h, hd, d), ("heads", None, "d_model"),
+                         fan_in=h * hd),
     }
     if cfg.qkv_bias:
         s["bq"] = TensorSpec((h, hd), ("heads", None), init="zeros")

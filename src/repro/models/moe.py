@@ -146,11 +146,6 @@ def moe_apply(
 def _moe_apply_shard_map(cfg, p, x, mesh, batch_axes, model_axis):
     from jax.sharding import PartitionSpec as P
 
-    try:
-        shard_map = jax.shard_map  # jax >= 0.6
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     m = dict(mesh.shape)[model_axis]  # works for Mesh and AbstractMesh
     e_pad = -(-e // m) * m
@@ -219,14 +214,8 @@ def _moe_apply_shard_map(cfg, p, x, mesh, batch_axes, model_axis):
                      P(model_axis, None)]
         args += [shared["w_gate"], shared["w_up"], shared["w_down"]]
     out_specs = (out_y_spec, P())
-    try:
-        fn = shard_map(
-            local_fn, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=out_specs, check_vma=False,
-        )
-    except TypeError:  # older JAX spells the replication check check_rep
-        fn = shard_map(
-            local_fn, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=out_specs, check_rep=False,
-        )
+    fn = jax.shard_map(
+        local_fn, mesh=mesh, in_specs=tuple(in_specs),
+        out_specs=out_specs, check_vma=False,
+    )
     return fn(*args)

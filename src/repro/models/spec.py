@@ -30,6 +30,9 @@ class TensorSpec:
     init: str = "normal"  # normal | zeros | ones | rglru_lambda
     scale: float = 1.0  # stddev multiplier for "normal"
     dtype: str = "bfloat16"
+    # inputs each output sums over ("normal": std = scale / sqrt(fan_in));
+    # None = the second-to-last dim, right for (in, out) matrices
+    fan_in: Optional[int] = None
 
     def __post_init__(self):
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
@@ -57,7 +60,7 @@ def _init_leaf(key: jax.Array, s: TensorSpec) -> jax.Array:
         u = jax.random.uniform(key, s.shape, jnp.float32, 0.9, 0.999)
         lam = jnp.log(jnp.expm1(-jnp.log(u) / 8.0))
         return lam.astype(dt)
-    fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
+    fan_in = s.fan_in or (s.shape[-2] if len(s.shape) >= 2 else s.shape[-1])
     std = s.scale / np.sqrt(max(fan_in, 1))
     return (jax.random.normal(key, s.shape, jnp.float32) * std).astype(dt)
 

@@ -1,5 +1,5 @@
-"""Per-kernel validation: Pallas (interpret=True) vs pure-jnp ref oracles,
-swept over shapes and dtypes, plus hypothesis property tests."""
+"""Per-kernel validation: Pallas (interpreted on the CPU backend) vs pure-jnp
+ref oracles, swept over shapes and dtypes, plus hypothesis property tests."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +8,7 @@ from _hyp import given, settings, st  # optional hypothesis (requirements-dev.tx
 
 from repro.kernels import ref
 from repro.kernels.fused_agg import fused_agg
+from repro.kernels.ops import interpret_mode
 from repro.kernels.pair_fuse import pair_fuse
 from repro.kernels.quant_agg import quant_agg, quantize
 
@@ -23,7 +24,7 @@ def test_fused_agg_matches_ref(k, n, dtype):
     u = jax.random.normal(key, (k, n), jnp.float32).astype(dtype)
     w = jnp.asarray(np.random.default_rng(0).dirichlet(np.ones(k)),
                     jnp.float32)
-    got = fused_agg(u, w)
+    got = fused_agg(u, w, interpret=interpret_mode())
     want = ref.fused_agg_ref(u, w)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
@@ -36,7 +37,7 @@ def test_fused_agg_matches_ref(k, n, dtype):
 def test_fused_agg_block_shape_sweep(bn, kb):
     u = jax.random.normal(jax.random.PRNGKey(0), (10, 5000), jnp.float32)
     w = jnp.full((10,), 0.1, jnp.float32)
-    got = fused_agg(u, w, bn=bn, kb=kb)
+    got = fused_agg(u, w, bn=bn, kb=kb, interpret=interpret_mode())
     np.testing.assert_allclose(got, ref.fused_agg_ref(u, w), rtol=1e-5,
                                atol=1e-5)
 
@@ -48,7 +49,7 @@ def test_pair_fuse_matches_ref(n, op, dtype):
     ka, kb_ = jax.random.split(jax.random.PRNGKey(n))
     a = jax.random.normal(ka, (n,), jnp.float32).astype(dtype)
     b = jax.random.normal(kb_, (n,), jnp.float32).astype(dtype)
-    got = pair_fuse(a, b, op=op, wa=0.3, wb=0.7)
+    got = pair_fuse(a, b, op=op, wa=0.3, wb=0.7, interpret=interpret_mode())
     want = ref.pair_fuse_ref(a, b, op, 0.3, 0.7)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
@@ -63,7 +64,8 @@ def test_quant_agg_matches_ref(k, n):
                            dtype=jnp.int8)
     s = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (k,))) * 0.01
     np.testing.assert_allclose(
-        quant_agg(q, s), ref.quant_agg_ref(q, s), rtol=1e-5, atol=1e-5
+        quant_agg(q, s, interpret=interpret_mode()), ref.quant_agg_ref(q, s),
+        rtol=1e-5, atol=1e-5
     )
 
 
@@ -99,7 +101,7 @@ def test_fused_agg_tile_override_parity(k, n, bn, kb, dtype):
                           jnp.float32).astype(dtype)
     w = jnp.asarray(np.random.default_rng(1).dirichlet(np.ones(k)),
                     jnp.float32)
-    got = fused_agg(u, w, bn=bn, kb=kb)
+    got = fused_agg(u, w, bn=bn, kb=kb, interpret=interpret_mode())
     want = ref.fused_agg_ref(u, w)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
@@ -118,7 +120,8 @@ def test_quant_agg_tile_override_parity(k, n, bn, kb):
                            dtype=jnp.int8)
     s = jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (k,))) * 0.01
     np.testing.assert_allclose(
-        quant_agg(q, s, bn=bn, kb=kb), ref.quant_agg_ref(q, s),
+        quant_agg(q, s, bn=bn, kb=kb, interpret=interpret_mode()),
+        ref.quant_agg_ref(q, s),
         rtol=1e-5, atol=1e-5,
     )
 
@@ -135,7 +138,8 @@ def test_pair_fuse_bn_override_parity(n, bn, op):
     a = jax.random.normal(ka, (n,), jnp.float32)
     b = jax.random.normal(kb_, (n,), jnp.float32)
     np.testing.assert_allclose(
-        pair_fuse(a, b, op=op, wa=0.3, wb=0.7, bn=bn),
+        pair_fuse(a, b, op=op, wa=0.3, wb=0.7, bn=bn,
+                  interpret=interpret_mode()),
         ref.pair_fuse_ref(a, b, op, 0.3, 0.7),
         rtol=1e-5, atol=1e-5,
     )
@@ -163,7 +167,7 @@ def test_fused_agg_weighted_mean_bounds(k, n, seed):
     """A convex combination never exceeds the per-coordinate min/max."""
     u = jax.random.normal(jax.random.PRNGKey(seed), (k, n), jnp.float32)
     w = jnp.full((k,), 1.0 / k, jnp.float32)
-    got = np.asarray(fused_agg(u, w))
+    got = np.asarray(fused_agg(u, w, interpret=interpret_mode()))
     lo = np.asarray(jnp.min(u, axis=0))
     hi = np.asarray(jnp.max(u, axis=0))
     assert (got >= lo - 1e-5).all() and (got <= hi + 1e-5).all()
@@ -177,5 +181,39 @@ def test_pair_fuse_commutative_ops(n, seed):
     b = jax.random.normal(kb_, (n,), jnp.float32)
     for op in ["mean", "max", "min"]:
         np.testing.assert_allclose(
-            pair_fuse(a, b, op=op), pair_fuse(b, a, op=op), rtol=1e-6
+            pair_fuse(a, b, op=op, interpret=interpret_mode()),
+            pair_fuse(b, a, op=op, interpret=interpret_mode()), rtol=1e-6
         )
+
+
+# ---- TPU semantics on the CPU ------------------------------------------------
+# The TPU interpreter models what the generic one does not: memory outside
+# the arrays reads as NaN, and an output block is never read back from HBM,
+# so a block revisited after another one would lose its partial sum. Ragged
+# last blocks on both axes and several K blocks per output block.
+@pytest.mark.parametrize("kernel,k,n,bn,kb", [
+    ("fused_agg", 13, 40_000, 16384, 8),
+    ("fused_agg", 32, 9216, 8192, 8),
+    ("quant_agg", 70, 5000, 4096, 32),
+    ("pair_fuse", 2, 9216, 8192, 2),
+])
+def test_kernels_under_tpu_interpreter(kernel, k, n, bn, kb):
+    from jax.experimental.pallas import tpu as pltpu
+
+    tpu = pltpu.InterpretParams()
+    key = jax.random.PRNGKey(k)
+    if kernel == "pair_fuse":
+        a, b = jax.random.normal(key, (2, n), jnp.float32)
+        got = pair_fuse(a, b, op="wsum", wa=0.3, wb=0.7, bn=bn, interpret=tpu)
+        want = ref.pair_fuse_ref(a, b, "wsum", 0.3, 0.7)
+    elif kernel == "fused_agg":
+        u = jax.random.normal(key, (k, n), jnp.float32)
+        w = jnp.linspace(0.1, 1.0, k, dtype=jnp.float32)
+        got = fused_agg(u, w, bn=bn, kb=kb, interpret=tpu)
+        want = ref.fused_agg_ref(u, w)
+    else:
+        q = jax.random.randint(key, (k, n), -127, 128, dtype=jnp.int8)
+        s = jnp.linspace(0.001, 0.01, k, dtype=jnp.float32)
+        got = quant_agg(q, s, bn=bn, kb=kb, interpret=tpu)
+        want = ref.quant_agg_ref(q, s)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)

@@ -5,9 +5,8 @@ vocab-parallel logits, pure-TP decode params) produce TPU-lowerable
 StableHLO without any devices. The full-size compile equivalent is the
 512-host-device dry-run (results/dryrun/)."""
 import jax
-import numpy as np
 import pytest
-from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AbstractMesh
 
 from repro import configs
 from repro.configs.base import InputShape
@@ -28,44 +27,8 @@ SHAPES = {
 }
 
 
-def _abstract_mesh(axes):
-    """Version-compat shim: newer JAX constructs AbstractMesh from
-    (name, size) pairs; other releases take (sizes, names) tuples."""
-    try:
-        return AbstractMesh(tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(s for _, s in axes),
-                            tuple(n for n, _ in axes))
-
-
-def _abstract_mesh_lowers() -> bool:
-    """Whether this JAX can lower jit in_shardings over an AbstractMesh.
-    Some releases (e.g. 0.4.37) only accept AbstractMesh inside shard_map
-    and raise on the device-assignment path during lowering."""
-    sh = NamedSharding(_abstract_mesh((("data", 2), ("model", 2))),
-                       PartitionSpec("data"))
-    try:
-        jax.jit(lambda x: x * 2, in_shardings=sh).trace(
-            jax.ShapeDtypeStruct((4, 4), "float32")
-        ).lower(lowering_platforms=("tpu",))
-        return True
-    except (ValueError, TypeError):
-        return False
-
-
-_ABSTRACT_OK = _abstract_mesh_lowers()
-
-
 def _make_mesh():
-    if _ABSTRACT_OK:
-        return _abstract_mesh((("data", 2), ("model", 2)))
-    # fall back to a concrete 2x2 mesh of (virtual) host devices; the
-    # lowering below still targets TPU via lowering_platforms
-    devs = jax.devices()
-    if len(devs) < 4:
-        pytest.skip("needs AbstractMesh lowering or >= 4 host devices "
-                    "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
-    return Mesh(np.array(devs[:4]).reshape(2, 2), ("data", "model"))
+    return AbstractMesh((2, 2), ("data", "model"))
 
 
 def _lower(cfg, shape, profile):

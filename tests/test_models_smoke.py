@@ -122,3 +122,16 @@ def test_block_patterns():
     assert bt[-2:] == ("rglru", "rglru")  # remainder stage
     vlm = configs.get_config("llama-3.2-vision-90b")
     assert vlm.block_types().count("xattn") == 20
+
+
+def test_attention_projections_init_by_their_true_fan_in():
+    """q/k/v sum over d_model and the output projection over heads x
+    head_dim: with the (in, out) matrix rule they were initialised by head
+    count instead, and a 12-layer model diverged in its first SGD step."""
+    cfg = configs.get_config("example-100m").reduced(
+        d_model=512, num_heads=8, num_kv_heads=2)
+    attn = M.init(cfg, jax.random.PRNGKey(0))["stage0"]["b0_attn"]["attn"]
+    std = {k: float(jnp.std(v.astype(jnp.float32))) for k, v in attn.items()}
+    for name in ("wq", "wk", "wv"):
+        np.testing.assert_allclose(std[name], 512 ** -0.5, rtol=0.05)
+    np.testing.assert_allclose(std["wo"], (8 * 64) ** -0.5, rtol=0.05)

@@ -1,0 +1,15 @@
+"""The benchmark's own tests run on the CPU, at small sizes: the Pallas
+kernels then run in the interpreter. Run from the checkout's root:
+
+    python -m pytest chipbench/tests
+"""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)

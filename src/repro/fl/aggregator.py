@@ -17,6 +17,7 @@ import jax
 
 from repro.core.queue import MessageQueue
 from repro.fl.fusion import FusionAlgorithm, FusionState, get_algorithm
+from repro.obs import span
 
 Pytree = Any
 
@@ -42,18 +43,19 @@ class AggregationExecutor:
     # ---- queue-driven incremental path ---------------------------------------
     def drain(self, round_idx: int, max_messages: int = 1 << 30) -> int:
         """Fold all pending updates for `round_idx` from the queue."""
-        topic = self.queue.topic(f"updates/{self.job_id}")
-        msgs = topic.poll(self.group, max_messages)
-        n = 0
-        for m in msgs:
-            if m.value["round"] != round_idx:
-                topic.commit(self.group, m.offset)  # stale round: drop
-                continue
-            w = self.alg.weight_of(m.value.get("n_examples", 1))
-            self.state = self.state.fold(m.value["update"], w)
-            topic.commit(self.group, m.offset)
-            n += 1
-        return n
+        with span("drain", round=round_idx):
+            topic = self.queue.topic(f"updates/{self.job_id}")
+            msgs = topic.poll(self.group, max_messages)
+            n = 0
+            for m in msgs:
+                if m.value["round"] != round_idx:
+                    topic.commit(self.group, m.offset)  # stale round: drop
+                    continue
+                w = self.alg.weight_of(m.value.get("n_examples", 1))
+                self.state = self.state.fold(m.value["update"], w)
+                topic.commit(self.group, m.offset)
+                n += 1
+            return n
 
     def checkpoint(self) -> None:
         """Preemption: persist the partial aggregate (§5.5)."""
@@ -75,11 +77,12 @@ class AggregationExecutor:
 
     def finish_round(self, global_model: Pytree, round_idx: int,
                      lr: float = 1.0) -> Pytree:
-        fused = self.state.result()
-        new_model = self.alg.apply(global_model, fused, lr)
-        self.queue.publish_fused(self.job_id, round_idx, new_model)
-        self.state = FusionState()
-        return new_model
+        with span("finish_round", round=round_idx):
+            fused = self.state.result()
+            new_model = self.alg.apply(global_model, fused, lr)
+            self.queue.publish_fused(self.job_id, round_idx, new_model)
+            self.state = FusionState()
+            return new_model
 
     # ---- batch path (lazy / batched strategies, and tests) -----------------------
     def aggregate(

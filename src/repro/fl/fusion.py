@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import accumulate, fuse_updates
+from repro.obs import span
 
 Pytree = Any
 
@@ -32,11 +33,12 @@ class FusionState:
     n_fused: int = 0
 
     def fold(self, update: Pytree, weight: float) -> "FusionState":
-        return FusionState(
-            acc=accumulate(self.acc, update, weight),
-            total_weight=self.total_weight + weight,
-            n_fused=self.n_fused + 1,
-        )
+        with span("fold"):
+            return FusionState(
+                acc=accumulate(self.acc, update, weight),
+                total_weight=self.total_weight + weight,
+                n_fused=self.n_fused + 1,
+            )
 
     def merge(self, other: "FusionState") -> "FusionState":
         """Merge two partial aggregates (parallel aggregation)."""

@@ -88,6 +88,7 @@ def weighted_sum(
         out_specs=pl.BlockSpec((bn,), lambda j, i: (j,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
         interpret=interpret,
+        name="fused_agg",
     )(w, x)
 
 

@@ -70,4 +70,5 @@ def pair_fuse(
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), a.dtype),
         interpret=interpret,
+        name="pair_fuse",
     )(w, a, b)

@@ -14,12 +14,17 @@ import dataclasses
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.queue import MessageQueue
 from repro.fl.fusion import FusionAlgorithm, FusionState, get_algorithm
 from repro.obs import span
 
 Pytree = Any
+
+
+def _copy(acc: Optional[Pytree]) -> Optional[Pytree]:
+    return jax.tree.map(jnp.copy, acc)
 
 
 class AggregationExecutor:
@@ -58,19 +63,24 @@ class AggregationExecutor:
             return n
 
     def checkpoint(self) -> None:
-        """Preemption: persist the partial aggregate (§5.5)."""
+        """Preemption: persist the partial aggregate (§5.5). The snapshot
+        holds a device copy of the accumulator, which the next fold
+        donates."""
         self.queue.checkpoint_partial(
             self.job_id,
-            {"acc": self.state.acc, "total_weight": self.state.total_weight,
+            {"acc": _copy(self.state.acc),
+             "total_weight": self.state.total_weight,
              "n_fused": self.state.n_fused},
         )
 
     def resume(self) -> bool:
+        """Continue from the latest snapshot, folding into a copy of it: the
+        snapshot stays valid for a later resume."""
         snap = self.queue.latest_partial(self.job_id)
         if snap is None:
             return False
         self.state = FusionState(
-            acc=snap["acc"], total_weight=snap["total_weight"],
+            acc=_copy(snap["acc"]), total_weight=snap["total_weight"],
             n_fused=snap["n_fused"],
         )
         return True
@@ -78,8 +88,7 @@ class AggregationExecutor:
     def finish_round(self, global_model: Pytree, round_idx: int,
                      lr: float = 1.0) -> Pytree:
         with span("finish_round", round=round_idx):
-            fused = self.state.result()
-            new_model = self.alg.apply(global_model, fused, lr)
+            new_model = self.state.finish(self.alg, global_model, lr)
             self.queue.publish_fused(self.job_id, round_idx, new_model)
             self.state = FusionState()
             return new_model
@@ -111,7 +120,6 @@ class AggregationExecutor:
             st = partials[0]
             for p in partials[1:]:
                 st = st.merge(p)
-        fused = st.result()
         if global_model is None:
-            return fused
-        return self.alg.apply(global_model, fused, lr)
+            return st.result()
+        return st.finish(self.alg, global_model, lr)

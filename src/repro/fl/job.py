@@ -39,7 +39,7 @@ from repro.data.partition import dirichlet_domain_mixes, party_sizes
 from repro.data.synthetic import SyntheticLM, SyntheticLMConfig
 from repro.fl.aggregator import AggregationExecutor
 from repro.fl.party import Party
-from repro.kernels import accumulate
+from repro.kernels import FlatAcc, accumulate
 from repro.models import model as M
 
 Pytree = Any
@@ -140,9 +140,15 @@ class FLJobRuntime:
     def _make_estimator(self) -> AggregationEstimator:
         """Offline t_pair measurement on the actual fusion kernel (§5.4)."""
         model_bytes = self.spec.model_bytes
+
+        def fold_pair(a, b):
+            # one fold into a fresh accumulator (the fold donates it);
+            # returns the device array, so the probe waits for the fold
+            acc = FlatAcc(jnp.asarray(a), jax.tree.structure(b), (b.shape,))
+            return accumulate(acc, jnp.asarray(b), 1.0).flat
+
         t_pair = measure_t_pair(
-            lambda a, b: accumulate(jnp.asarray(a), jnp.asarray(b), 1.0),
-            min(model_bytes, 4 << 20),  # cap the probe size on CPU
+            fold_pair, min(model_bytes, 4 << 20),  # cap the probe size on CPU
         )
         # scale to the true model size (fusion is linear in bytes)
         t_pair *= model_bytes / min(model_bytes, 4 << 20)

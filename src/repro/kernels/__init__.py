@@ -1,4 +1,5 @@
 from repro.kernels.ops import (  # noqa: F401
+    FlatAcc,
     accumulate,
     fuse_quantized,
     fuse_updates,

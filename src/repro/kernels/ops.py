@@ -1,14 +1,19 @@
 """jit'd public wrappers over the Pallas kernels, operating on model-update
 PYTREES (the paper's "list of one-dimensional vectors, one per layer").
 
-All entry points accept/return pytrees of arrays; leaves are flattened,
-fused leaf-wise by the kernels, and reshaped back. The kernel mode is decided
-here, once, from the backend (`interpret_mode`): on the CPU backend the
-Pallas kernel bodies run in the interpreter, on a TPU they are compiled.
+The batch drains (``fuse_updates``, ``fuse_quantized``) fuse leaf by leaf:
+each leaf is flattened, fused by its kernel and reshaped back. The streaming
+fold (``accumulate``) keeps its accumulator flat (``FlatAcc``: every leaf end
+to end in one fp32 vector) and folds a whole update in one compiled program,
+in place. The kernel mode is decided here, once, from the backend
+(`interpret_mode`): on the CPU backend the Pallas kernel bodies run in the
+interpreter, on a TPU they are compiled.
 """
 from __future__ import annotations
 
-from typing import Any, List, Optional, Sequence
+import functools
+import math
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,32 +69,90 @@ def fuse_updates(
     return jax.tree.unflatten(treedef, fused)
 
 
+@jax.tree_util.register_pytree_node_class
+class FlatAcc:
+    """The streaming accumulator: one 1-D fp32 array holding every leaf of a
+    model update end to end, in ``jax.tree.leaves`` order, with no padding.
+
+    Its layout (the update's tree structure and leaf shapes) is static pytree
+    data, so a FlatAcc passes through ``jax.jit`` as one array, a program is
+    compiled once per layout, and a checkpoint of it is the value alone."""
+
+    def __init__(self, flat: jax.Array, treedef, shapes: Tuple[tuple, ...]):
+        self.flat = flat
+        self.treedef = treedef
+        self.shapes = shapes
+
+    def tree_flatten(self):
+        return (self.flat,), (self.treedef, self.shapes)
+
+    @classmethod
+    def tree_unflatten(cls, layout, children):
+        return cls(children[0], *layout)
+
+    def tree(self) -> Pytree:
+        """The accumulator as the update's tree of fp32 leaves: a slice and
+        a reshape per leaf, meant to run inside a jitted program."""
+        leaves, off = [], 0
+        for shape in self.shapes:
+            n = math.prod(shape)
+            leaves.append(self.flat[off:off + n].reshape(shape))
+            off += n
+        return jax.tree.unflatten(self.treedef, leaves)
+
+
+def _flat(leaves: List[jax.Array]) -> jax.Array:
+    return jnp.concatenate([l.reshape(-1).astype(jnp.float32) for l in leaves])
+
+
+@jax.jit
+def first_fold(leaves: List[jax.Array], weight) -> jax.Array:
+    """The first update of a round, flat and weighted: ``weight * u``."""
+    return _flat(leaves) * weight
+
+
+@functools.partial(jax.jit, donate_argnums=0,
+                   static_argnames=("bn", "interpret"))
+def fold_into(acc: jax.Array, leaves: List[jax.Array], w: jax.Array, *,
+              bn: Optional[int] = None, interpret: bool) -> jax.Array:
+    """``w[0] * acc + w[1] * u`` for the flat update ``u``, by one
+    ``pair_fuse`` written into ``acc``'s buffer: ``acc`` is donated, the
+    update is not. Both weights arrive at run time, as the kernel reads
+    them, so no compiler folds the ``1.0 *`` away and rounds differently."""
+    return pair_fuse(acc, _flat(leaves), op="wsum", wa=w[0], wb=w[1],
+                     alias_a=True, interpret=interpret, **_tile_kwargs(bn))
+
+
 def accumulate(
-    acc: Optional[Pytree],
+    acc: Optional[FlatAcc],
     update: Pytree,
     weight: float,
     *,
     bn: Optional[int] = None,
-) -> Pytree:
+) -> FlatAcc:
     """Streaming (incremental) fusion: acc <- acc + weight*update.
 
     This is the eager/JIT aggregator's inner operation: each arriving update
-    is folded into the running fp32 accumulator with the pair_fuse kernel,
-    so aggregation state is one model-sized buffer regardless of K."""
+    is folded into the running flat fp32 accumulator by one compiled program
+    (the update's leaves concatenated, then one pair_fuse), so aggregation
+    state is one model-sized buffer regardless of K. ``acc`` is consumed:
+    its buffer is donated and holds the result. ``update`` may also be a
+    FlatAcc of the same layout (merging two partial aggregates). The weight
+    is a traced argument: no weight compiles a program of its own."""
+    if isinstance(update, FlatAcc):
+        leaves, treedef, shapes = [update.flat], update.treedef, update.shapes
+    else:
+        leaves, treedef = jax.tree.flatten(update)
+        shapes = tuple(tuple(l.shape) for l in leaves)
     if acc is None:
-        return jax.tree.map(
-            lambda u: (u.astype(jnp.float32) * weight), update
-        )
-    return jax.tree.map(
-        lambda a, u: pair_fuse(
-            a.reshape(-1), u.astype(jnp.float32).reshape(-1),
-            op="wsum", wa=1.0, wb=float(weight),
-            interpret=interpret_mode(),
-            **_tile_kwargs(bn),
-        ).reshape(a.shape),
-        acc,
-        update,
-    )
+        return FlatAcc(first_fold(leaves, float(weight)), treedef, shapes)
+    if treedef != acc.treedef or shapes != acc.shapes:
+        raise ValueError(
+            f"update layout {treedef} {shapes} does not match the "
+            f"accumulator's {acc.treedef} {acc.shapes}")
+    flat = fold_into(acc.flat, leaves, np.asarray([1.0, weight], np.float32),
+                     bn=bn, interpret=interpret_mode())
+    return FlatAcc(flat, treedef, shapes)
 
 
 def fuse_quantized(

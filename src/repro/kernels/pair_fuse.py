@@ -7,6 +7,11 @@ one pair at a time as they arrive. f is selected statically: mean, weighted
 sum, max, min. Elementwise and bandwidth-bound; (8, 1024) fp32 tiles. The
 two weights are scalars held in SMEM.
 
+With ``alias_a`` the output is written into ``a``'s buffer
+(``input_output_aliases``): a caller that donates ``a`` then folds in place,
+holding one accumulator, not two. A caller that does not donate ``a`` would
+pay XLA a copy of it, so the alias is off by default.
+
 The block size ``bn`` is tunable (multiple of 1024 = 8*128 fp32 lanes);
 `repro.kernels.autotune` picks it per model size by minimising modeled HBM
 traffic (padding waste vs VMEM pressure). The default matches the
@@ -44,7 +49,8 @@ def _make_kernel(op: str):
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("op", "bn", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("op", "bn", "alias_a", "interpret"))
 def pair_fuse(
     a: jax.Array,  # (N,)
     b: jax.Array,  # (N,)
@@ -53,6 +59,7 @@ def pair_fuse(
     wa: float = 0.5,
     wb: float = 0.5,
     bn: int = DEFAULT_BN,
+    alias_a: bool = False,
     interpret: bool,
 ) -> jax.Array:
     (n,) = a.shape
@@ -69,6 +76,7 @@ def pair_fuse(
         ],
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), a.dtype),
+        input_output_aliases={1: 0} if alias_a else {},
         interpret=interpret,
         name="pair_fuse",
     )(w, a, b)

@@ -15,10 +15,12 @@ The aggregator's served path opens three (``fl/aggregator.py``,
   repro.drain(round=r)         ``AggregationExecutor.drain``: poll, weight,
                                fold and commit
   repro.fold                   ``FusionState.fold``: one per update folded,
-                               around ``kernels.accumulate``'s per-leaf
-                               dispatch
+                               around ``kernels.accumulate``'s dispatch of
+                               one compiled program for the whole update
   repro.finish_round(round=r)  ``AggregationExecutor.finish_round``:
-                               ``result``, ``apply`` and ``publish_fused``
+                               ``FusionState.finish`` (the mean and
+                               ``apply``, one compiled program) and
+                               ``publish_fused``
 """
 from __future__ import annotations
 

@@ -111,6 +111,51 @@ def test_aggregator_queue_roundtrip_and_preemption():
     assert len(q.topic("fused/job")) == 1
 
 
+def test_checkpoint_outlives_the_folds_that_donate_the_accumulator():
+    """A fold donates the accumulator, so a checkpoint holds a device copy:
+    an executor checkpoints, keeps folding and finishes; a second executor
+    resumes from the snapshot and finishes; both publish the direct result.
+    A state that was folded from is spent and raises when used."""
+    cfg = tiny_cfg()
+    gp = M.init(cfg, jax.random.PRNGKey(0))
+    q = MessageQueue()
+    nex = [10, 20, 30, 40]
+    ups = [jax.tree.map(lambda p, i=i: p * (1 + 0.01 * i) + 0.01 * i, gp)
+           for i in range(4)]
+    for i, u in enumerate(ups):
+        q.publish_update("job", f"p{i}", u, round_idx=0, n_examples=nex[i])
+    direct = AggregationExecutor("direct", "fedavg").aggregate(ups, nex, gp)
+
+    first = AggregationExecutor("job", "fedavg", q, group="first")
+    assert first.drain(0, max_messages=2) == 2
+    first.checkpoint()
+    at_checkpoint = first.state
+    assert first.drain(0) == 2
+    with pytest.raises(RuntimeError, match="folded or merged"):
+        at_checkpoint.result()
+    kept_folding = first.finish_round(gp, 0)
+
+    second = AggregationExecutor("job", "fedavg", q, group="second")
+    q.topic("updates/job").commit("second", 1)  # the snapshot's offset
+    assert second.resume()
+    assert second.drain(0) == 2
+    resumed = second.finish_round(gp, 0)
+    # resume folded into a copy: the snapshot still holds the first two
+    third = AggregationExecutor("job", "fedavg", q)
+    assert third.resume()
+    partial = AggregationExecutor("p", "fedavg").aggregate(ups[:2], nex[:2])
+    for x, y in zip(jax.tree.leaves(third.state.result()),
+                    jax.tree.leaves(partial)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    for got in (kept_folding, resumed):
+        assert jax.tree.structure(got) == jax.tree.structure(direct)
+        for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(direct)):
+            assert x.dtype == y.dtype
+            np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                          np.asarray(y, np.float32))
+
+
 def test_parallel_workers_equal_single_worker():
     cfg = tiny_cfg()
     gp = M.init(cfg, jax.random.PRNGKey(0))

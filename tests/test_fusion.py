@@ -131,3 +131,145 @@ def test_checkpoint_resume_roundtrip():
         resumed = resumed.fold(u, w)
     np.testing.assert_allclose(resumed.result()["w0"], direct.result()["w0"],
                                rtol=1e-5)
+
+
+# ---- the flat accumulator: one program per fold and per finish --------------
+def _mixed_tree(seed, dtype):
+    """A scalar, 1-D, 2-D and 4-D leaf in nested containers, 13,208
+    elements: more than one ``pair_fuse`` block, as every model is. (Where
+    the whole vector fits one block, XLA's CPU backend inlines the
+    interpreted kernel's single step into one fusion with the concat and
+    contracts ``a + w*u`` into a fused multiply-add, one rounding fewer;
+    the compiled TPU kernel rounds as written.)"""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return {
+        "scale": jax.random.normal(ks[0], ()).astype(dtype),
+        "layers": [jax.random.normal(ks[1], (37,)).astype(dtype),
+                   jax.random.normal(ks[2], (9, 130)).astype(dtype)],
+        "conv": jax.random.normal(ks[3], (3, 2, 40, 50)).astype(dtype),
+    }
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flat_fold_matches_per_leaf_arithmetic(dtype):
+    """Each element sees the per-leaf operations in the same order — ``w*u``
+    first, then ``1.0*a + w*u``, then ``/ tw`` — so the flat fold equals
+    them exactly, and the mean comes back in the update's tree."""
+    ups = [_mixed_tree(s, dtype) for s in range(4)]
+    ws = [3.0, 0.7, 12.0, 1.5]
+    want = jax.tree.map(lambda u: u.astype(jnp.float32) * ws[0], ups[0])
+    for u, w in zip(ups[1:], ws[1:]):
+        want = jax.tree.map(
+            lambda a, x, w=w: 1.0 * a + w * x.astype(jnp.float32), want, u)
+    tw = sum(ws)
+    want = jax.tree.map(lambda a: a / tw, want)
+
+    st_ = FusionState()
+    for u, w in zip(ups, ws):
+        st_ = st_.fold(u, w)
+    got = st_.result()
+    assert jax.tree.structure(got) == jax.tree.structure(ups[0])
+    for g, x, u in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                       jax.tree.leaves(ups[0])):
+        assert g.shape == u.shape and g.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(x))
+
+    # the published model keeps the global model's tree, shapes and dtypes
+    model = _mixed_tree(99, dtype)
+    for alg in (FedAvg(), FedSGD()):
+        new = st_.finish(alg, model, 0.5)
+        assert jax.tree.structure(new) == jax.tree.structure(model)
+        for n, m, e in zip(jax.tree.leaves(new), jax.tree.leaves(model),
+                           jax.tree.leaves(alg.apply(model, want, 0.5))):
+            assert n.shape == m.shape and n.dtype == m.dtype
+            np.testing.assert_array_equal(np.asarray(n, np.float32),
+                                          np.asarray(e, np.float32))
+
+
+def _backend_compiles(fn) -> int:
+    events = []
+
+    def on(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            events.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        fn()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    return len(events)
+
+
+def test_folding_the_same_shapes_again_compiles_nothing():
+    """The compile key is the tree and its leaf shapes; the weights, the
+    total weight and the server step are traced."""
+    model = _mixed_tree(7, jnp.float32)
+
+    def round_(seed, ws, lr):
+        st_ = FusionState()
+        for i, w in enumerate(ws):
+            st_ = st_.fold(_mixed_tree(seed + i, jnp.float32), w)
+        jax.block_until_ready((st_.result(), st_.finish(FedSGD(), model, lr)))
+
+    round_(0, [1.0, 2.0, 3.0], 1.0)
+    assert _backend_compiles(lambda: round_(10, [5.0, 0.25, 7.5, 2.0],
+                                            0.3)) == 0
+
+
+def _count_primitive(jaxpr, name) -> int:
+    """Equations named ``name`` in ``jaxpr`` and the jaxprs nested in it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            n += 1
+            continue
+        for v in eqn.params.values():
+            for j in (v if isinstance(v, (list, tuple)) else [v]):
+                j = getattr(j, "jaxpr", j)  # a ClosedJaxpr's Jaxpr
+                if hasattr(j, "eqns"):
+                    n += _count_primitive(j, name)
+    return n
+
+
+def test_fold_is_one_program_with_one_kernel():
+    """A fold of the whole tree is one compiled program holding exactly one
+    pallas_call; a finish is one program too."""
+    from repro.kernels import FlatAcc, accumulate
+
+    st_ = FusionState().fold(_mixed_tree(0, jnp.float32), 2.0)
+    acc, update = st_.acc, _mixed_tree(1, jnp.bfloat16)
+    fold = jax.make_jaxpr(
+        lambda flat, u: accumulate(FlatAcc(flat, acc.treedef, acc.shapes),
+                                   u, 0.5).flat)(acc.flat, update)
+    assert len(fold.jaxpr.eqns) == 1
+    assert _count_primitive(fold.jaxpr, "pallas_call") == 1
+
+    model = _mixed_tree(2, jnp.float32)
+    finish = jax.make_jaxpr(
+        lambda flat: FusionState(FlatAcc(flat, acc.treedef, acc.shapes),
+                                 2.0).finish(FedSGD(), model))(acc.flat)
+    assert len(finish.jaxpr.eqns) == 1
+    assert _count_primitive(finish.jaxpr, "pallas_call") == 0
+
+
+def test_fold_and_merge_spend_the_state_they_consume():
+    """fold and merge donate the accumulator to the state they return; the
+    consumed state raises when used, and merge never spends ``other``."""
+    ups = _updates(3, shapes=((4, 3),))
+    a = FusionState().fold(ups[0], 1.0)
+    b = a.fold(ups[1], 2.0)
+    for use in (lambda: a.result(), lambda: a.fold(ups[2], 1.0),
+                lambda: a.merge(b), lambda: b.merge(a),
+                lambda: a.finish(FedAvg(), ups[0])):
+        with pytest.raises(RuntimeError, match="folded or merged"):
+            use()
+    other = FusionState().fold(ups[2], 4.0)
+    merged = b.merge(other)
+    with pytest.raises(RuntimeError):
+        b.result()
+    np.testing.assert_array_equal(other.result()["w0"], ups[2]["w0"])
+    np.testing.assert_allclose(
+        merged.result()["w0"],
+        (ups[0]["w0"] + 2 * ups[1]["w0"] + 4 * ups[2]["w0"]) / 7,
+        rtol=1e-6, atol=1e-6)

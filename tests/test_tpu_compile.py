@@ -1,4 +1,5 @@
-"""Compile the three fusion kernels for one TPU v5e chip, without a chip.
+"""Compile the three fusion kernels, and the streaming aggregator's fold and
+finish programs, for one TPU v5e chip, without a chip.
 
 The TPU compiler is installed with JAX and compiles for a described
 topology, so these tests refuse what the chip's compiler would refuse
@@ -13,6 +14,7 @@ The topology is described only inside the module fixture: describing it
 loads the TPU library, which one process at a time may hold.
 """
 import json
+import math
 import pathlib
 
 import jax
@@ -20,7 +22,10 @@ import jax.numpy as jnp
 import pytest
 
 from benchmarks.workloads import WORKLOADS
+from repro.fl.fusion import finished_model, get_algorithm
+from repro.kernels import FlatAcc
 from repro.kernels import autotune as at
+from repro.kernels.ops import first_fold, fold_into
 from repro.kernels.fused_agg import fused_agg
 from repro.kernels.pair_fuse import pair_fuse
 from repro.kernels.quant_agg import quant_agg
@@ -30,8 +35,8 @@ HBM_BYTES = 16 * 2**30
 #: updates per batch drain at the §6.3 sizes
 K_FUSED, K_QUANT = 8, 64
 
-_BASELINE = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / \
-    "kernel_baseline.json"
+_ROOT = pathlib.Path(__file__).resolve().parents[1]
+_BASELINE = _ROOT / "benchmarks" / "kernel_baseline.json"
 
 
 @pytest.fixture(scope="module")
@@ -115,3 +120,40 @@ def test_kernel_compiles_for_short_leaves(one_chip, kernel, k, n):
     each kernel must still compile with its default tile."""
     spec = at.KERNELS[kernel]
     _compile(one_chip, kernel, k, n, spec.default_bn, spec.default_kb)
+
+
+@pytest.mark.parametrize("config", ["vgg16", "effnetb7"])
+def test_fold_and_finish_compile_at_benchmark_leaves(one_chip, config):
+    """The served path's three programs at the benchmark's leaf lists (32
+    and 711 leaves): the first fold, a fold (one pair_fuse, written in
+    place into the donated accumulator) and a round's finish."""
+    cfg = json.loads((_ROOT / "chipbench" / "configs" /
+                      f"{config}.json").read_text())
+    f32 = jnp.float32
+    arg = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt,
+                                                     sharding=one_chip)
+    shapes = tuple(tuple(s) for _, s in cfg["leaves"])
+    leaves = [arg(s, jnp.dtype(cfg["dtype"])) for s in shapes]
+    n = sum(math.prod(s) for s in shapes)
+    assert n == cfg["n_params"]
+    acc = arg((n,))
+
+    first = first_fold.lower(leaves, arg(())).compile()
+    assert first.memory_analysis().output_size_in_bytes >= 4 * n
+
+    fold = fold_into.lower(acc, leaves, arg((2,)), interpret=False).compile()
+    assert fold.as_text().count("tpu_custom_call") >= 1
+    mem = fold.memory_analysis()
+    # the whole output is the donated accumulator's buffer
+    assert mem.alias_size_in_bytes == mem.output_size_in_bytes >= 4 * n
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < HBM_BYTES)
+
+    flat = FlatAcc(acc, jax.tree.structure(leaves), shapes)
+    finish = finished_model.lower(
+        get_algorithm(cfg["algorithm"]), flat, arg(()), leaves,
+        arg(())).compile()
+    mem = finish.memory_analysis()
+    assert mem.output_size_in_bytes >= 4 * n
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes < HBM_BYTES)

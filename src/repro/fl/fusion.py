@@ -73,9 +73,9 @@ class FusionState:
         return self.acc
 
     def fold(self, update: Pytree, weight: float) -> "FusionState":
-        with span("fold"):
+        with span("fold") as s:
             self._check_live()
-            acc = accumulate(self.acc, update, weight)
+            acc = accumulate(self.acc, update, weight, span=s)
             self.spent = True
             return FusionState(acc, self.total_weight + weight,
                                self.n_fused + 1)
@@ -103,7 +103,8 @@ class FusionState:
                lr: float = 1.0) -> Pytree:
         """``alg.apply(global_model, self.result(), lr)`` in one compiled
         program per algorithm and layout: the new global model, in the
-        global model's tree and dtypes."""
+        global model's tree and dtypes (a bf16 model rounded once, from the
+        fp32 mean)."""
         return finished_model(alg, self._folded(), self.total_weight,
                               global_model, lr)
 

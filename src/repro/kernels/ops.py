@@ -5,9 +5,10 @@ The batch drains (``fuse_updates``, ``fuse_quantized``) fuse leaf by leaf:
 each leaf is flattened, fused by its kernel and reshaped back. The streaming
 fold (``accumulate``) keeps its accumulator flat (``FlatAcc``: every leaf end
 to end in one fp32 vector) and folds a whole update in one compiled program,
-in place. The kernel mode is decided here, once, from the backend
-(`interpret_mode`): on the CPU backend the Pallas kernel bodies run in the
-interpreter, on a TPU they are compiled.
+in place: the update is staged end to end at its own width (a bf16 update as
+bf16) and upcast to fp32 where the kernel reads it. The kernel mode is
+decided here, once, from the backend (`interpret_mode`): on the CPU backend
+the Pallas kernel bodies run in the interpreter, on a TPU they are compiled.
 """
 from __future__ import annotations
 
@@ -72,7 +73,8 @@ def fuse_updates(
 @jax.tree_util.register_pytree_node_class
 class FlatAcc:
     """The streaming accumulator: one 1-D fp32 array holding every leaf of a
-    model update end to end, in ``jax.tree.leaves`` order, with no padding.
+    model update end to end, in ``jax.tree.leaves`` order, with no padding,
+    whatever the update's dtype.
 
     Its layout (the update's tree structure and leaf shapes) is static pytree
     data, so a FlatAcc passes through ``jax.jit`` as one array, a program is
@@ -102,13 +104,28 @@ class FlatAcc:
 
 
 def _flat(leaves: List[jax.Array]) -> jax.Array:
-    return jnp.concatenate([l.reshape(-1).astype(jnp.float32) for l in leaves])
+    """The leaves end to end in one 1-D array of their own dtype (a mixed
+    tree's promoted one): a bf16 update is staged as bf16, not as fp32."""
+    dtype = jnp.result_type(*(l.dtype for l in leaves))
+    return jnp.concatenate([l.reshape(-1).astype(dtype) for l in leaves])
+
+
+@functools.lru_cache(maxsize=64)
+def _layout(sig: Tuple[tuple, ...]) -> Tuple[Tuple[tuple, ...], str, int]:
+    """For an update whose leaves have ``sig`` (shape and dtype each): its
+    leaf shapes, and what a fold reads of it, the staged dtype's name and
+    the update's bytes. Cached per layout: a fold finds them in the one pass
+    over its leaves that it makes anyway."""
+    shapes = tuple(tuple(s) for s, _ in sig)
+    nbytes = sum(math.prod(s) * d.itemsize for s, d in sig)
+    return shapes, jnp.result_type(*(d for _, d in sig)).name, nbytes
 
 
 @jax.jit
 def first_fold(leaves: List[jax.Array], weight) -> jax.Array:
-    """The first update of a round, flat and weighted: ``weight * u``."""
-    return _flat(leaves) * weight
+    """The first update of a round, flat and weighted: ``weight * u``,
+    multiplied in fp32 as every later fold is."""
+    return _flat(leaves).astype(jnp.float32) * weight
 
 
 @functools.partial(jax.jit, donate_argnums=0,
@@ -117,8 +134,10 @@ def fold_into(acc: jax.Array, leaves: List[jax.Array], w: jax.Array, *,
               bn: Optional[int] = None, interpret: bool) -> jax.Array:
     """``w[0] * acc + w[1] * u`` for the flat update ``u``, by one
     ``pair_fuse`` written into ``acc``'s buffer: ``acc`` is donated, the
-    update is not. Both weights arrive at run time, as the kernel reads
-    them, so no compiler folds the ``1.0 *`` away and rounds differently."""
+    update is not. ``u`` stays at the update's width: the kernel upcasts it
+    to fp32 as it reads it. Both weights arrive at run time, as the kernel
+    reads them, so no compiler folds the ``1.0 *`` away and rounds
+    differently."""
     return pair_fuse(acc, _flat(leaves), op="wsum", wa=w[0], wb=w[1],
                      alias_a=True, interpret=interpret, **_tile_kwargs(bn))
 
@@ -129,6 +148,7 @@ def accumulate(
     weight: float,
     *,
     bn: Optional[int] = None,
+    span=None,
 ) -> FlatAcc:
     """Streaming (incremental) fusion: acc <- acc + weight*update.
 
@@ -138,12 +158,18 @@ def accumulate(
     state is one model-sized buffer regardless of K. ``acc`` is consumed:
     its buffer is donated and holds the result. ``update`` may also be a
     FlatAcc of the same layout (merging two partial aggregates). The weight
-    is a traced argument: no weight compiles a program of its own."""
+    is a traced argument: no weight compiles a program of its own. An open
+    ``repro.obs.span`` given as ``span`` is told what the fold read: the
+    staged ``dtype`` and the update's ``nbytes``."""
     if isinstance(update, FlatAcc):
         leaves, treedef, shapes = [update.flat], update.treedef, update.shapes
+        dtype, nbytes = update.flat.dtype.name, update.flat.nbytes
     else:
         leaves, treedef = jax.tree.flatten(update)
-        shapes = tuple(tuple(l.shape) for l in leaves)
+        shapes, dtype, nbytes = _layout(
+            tuple((l.shape, l.dtype) for l in leaves))
+    if span is not None:
+        span.set_metadata(dtype=dtype, nbytes=nbytes)
     if acc is None:
         return FlatAcc(first_fold(leaves, float(weight)), treedef, shapes)
     if treedef != acc.treedef or shapes != acc.shapes:

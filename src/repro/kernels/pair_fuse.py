@@ -4,8 +4,8 @@
 
 used by incremental (streaming / eager) aggregation, where updates are fused
 one pair at a time as they arrive. f is selected statically: mean, weighted
-sum, max, min. Elementwise and bandwidth-bound; (8, 1024) fp32 tiles. The
-two weights are scalars held in SMEM.
+sum, max, min. Elementwise and bandwidth-bound, over 1-D blocks; operands are
+upcast to fp32 in the kernel (a fold's bf16 update); weights sit in SMEM.
 
 With ``alias_a`` the output is written into ``a``'s buffer
 (``input_output_aliases``): a caller that donates ``a`` then folds in place,

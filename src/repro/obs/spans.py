@@ -14,9 +14,11 @@ The aggregator's served path opens three (``fl/aggregator.py``,
 
   repro.drain(round=r)         ``AggregationExecutor.drain``: poll, weight,
                                fold and commit
-  repro.fold                   ``FusionState.fold``: one per update folded,
+  repro.fold(dtype=, nbytes=)  ``FusionState.fold``: one per update folded,
                                around ``kernels.accumulate``'s dispatch of
-                               one compiled program for the whole update
+                               one compiled program for the whole update;
+                               what it read, the staged update's dtype and
+                               the update's bytes, cached per layout
   repro.finish_round(round=r)  ``AggregationExecutor.finish_round``:
                                ``FusionState.finish`` (the mean and
                                ``apply``, one compiled program) and

@@ -122,18 +122,21 @@ def test_kernel_compiles_for_short_leaves(one_chip, kernel, k, n):
     _compile(one_chip, kernel, k, n, spec.default_bn, spec.default_kb)
 
 
-@pytest.mark.parametrize("config", ["vgg16", "effnetb7"])
+@pytest.mark.parametrize("config", ["vgg16", "effnetb7", "kanana2-30b"])
 def test_fold_and_finish_compile_at_benchmark_leaves(one_chip, config):
-    """The served path's three programs at the benchmark's leaf lists (32
-    and 711 leaves): the first fold, a fold (one pair_fuse, written in
-    place into the donated accumulator) and a round's finish."""
+    """The served path's three programs at the leaf lists of
+    ``chipbench/configs`` (32, 711 and 73 leaves; the last bf16): the first
+    fold, a fold (one pair_fuse, written in place into the donated fp32
+    accumulator) and a round's finish, which publishes the model in its own
+    dtype."""
     cfg = json.loads((_ROOT / "chipbench" / "configs" /
                       f"{config}.json").read_text())
     f32 = jnp.float32
     arg = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt,
                                                      sharding=one_chip)
     shapes = tuple(tuple(s) for _, s in cfg["leaves"])
-    leaves = [arg(s, jnp.dtype(cfg["dtype"])) for s in shapes]
+    dtype = jnp.dtype(cfg["dtype"])
+    leaves = [arg(s, dtype) for s in shapes]
     n = sum(math.prod(s) for s in shapes)
     assert n == cfg["n_params"]
     acc = arg((n,))
@@ -154,6 +157,6 @@ def test_fold_and_finish_compile_at_benchmark_leaves(one_chip, config):
         get_algorithm(cfg["algorithm"]), flat, arg(()), leaves,
         arg(())).compile()
     mem = finish.memory_analysis()
-    assert mem.output_size_in_bytes >= 4 * n
+    assert mem.output_size_in_bytes >= dtype.itemsize * n
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes < HBM_BYTES)

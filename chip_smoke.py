@@ -7,9 +7,9 @@ Drives the system's main path once through its public entry point,
 ``Platform().train``, on the full ``example-100m`` config (88.1M
 parameters; random weights from a seed): 4 FedProx parties train locally
 for 2 rounds on the chip and publish their bf16 updates to the message
-queue, and the aggregator folds each update with the compiled ``pair_fuse``
-kernel and publishes the fused model. Then, on what the run left in the
-queue:
+queue, and the aggregator folds each update into its fp32 slab
+accumulator (one XLA multiply-add per slab) and publishes the fused model.
+Then, on what the run left in the queue:
 
   a  train+fuse   each round's fp32 fold against a plain jax.numpy fp32
                   weighted mean of that round's updates, and the published
@@ -214,9 +214,9 @@ def check_drains(result, k: int = K_DRAIN) -> dict:
 
 # ---- phase d --------------------------------------------------------------
 def kernel_programs(result) -> dict:
-    """Phase d: compile each kernel as the aggregator calls it, at the
-    largest leaf of an update; True where the program holds the Mosaic
-    kernel (``tpu_custom_call``), False where it was interpreted."""
+    """Phase d: compile each kernel at the largest leaf of an update; True
+    where the program holds the Mosaic kernel (``tpu_custom_call``), False
+    where it was interpreted."""
     from repro.kernels import interpret_mode
     from repro.kernels.fused_agg import fused_agg
     from repro.kernels.pair_fuse import pair_fuse

@@ -23,21 +23,21 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import FlatAcc, accumulate, fuse_updates
+from repro.kernels import SlabAcc, accumulate, fuse_updates
 from repro.obs import span
 
 Pytree = Any
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))
-def weighted_mean(acc: FlatAcc, total_weight, dtype=None) -> Pytree:
+def weighted_mean(acc: SlabAcc, total_weight, dtype=None) -> Pytree:
     """The accumulator over the total weight, as the updates' tree."""
     return jax.tree.map(lambda a: (a / total_weight).astype(dtype or a.dtype),
                         acc.tree())
 
 
 @functools.partial(jax.jit, static_argnums=0)
-def finished_model(alg: "FusionAlgorithm", acc: FlatAcc, total_weight,
+def finished_model(alg: "FusionAlgorithm", acc: SlabAcc, total_weight,
                    global_model: Pytree, lr) -> Pytree:
     """The round's new global model: the mean and ``alg.apply`` in one
     program; the total weight and the step are traced."""
@@ -48,13 +48,13 @@ def finished_model(alg: "FusionAlgorithm", acc: FlatAcc, total_weight,
 class FusionState:
     """Checkpointable partial aggregate: fp32 accumulator + total weight.
 
-    ``acc`` is a flat fp32 accumulator (`repro.kernels.FlatAcc`) that this
-    state owns: ``fold`` and ``merge`` donate its buffer to the state they
-    return, and this state is then spent: using it again raises. A
+    ``acc`` is an fp32 slab accumulator (`repro.kernels.SlabAcc`) that
+    this state owns: ``fold`` and ``merge`` donate its buffers to the state
+    they return, and this state is then spent: using it again raises. A
     snapshot that must outlive the next fold holds a copy
     (``AggregationExecutor.checkpoint``)."""
 
-    acc: Optional[FlatAcc] = None
+    acc: Optional[SlabAcc] = None
     total_weight: float = 0.0
     n_fused: int = 0
     spent: bool = dataclasses.field(default=False, init=False,
@@ -66,7 +66,7 @@ class FusionState:
                 "this FusionState was folded or merged into a new state, "
                 "which owns its accumulator now; use that state")
 
-    def _folded(self) -> FlatAcc:
+    def _folded(self) -> SlabAcc:
         self._check_live()
         if self.acc is None or self.total_weight <= 0:
             raise ValueError("no update has been folded")

@@ -39,7 +39,7 @@ from repro.data.partition import dirichlet_domain_mixes, party_sizes
 from repro.data.synthetic import SyntheticLM, SyntheticLMConfig
 from repro.fl.aggregator import AggregationExecutor
 from repro.fl.party import Party
-from repro.kernels import FlatAcc, accumulate
+from repro.kernels import SlabAcc, accumulate, slab_plan
 from repro.models import model as M
 
 Pytree = Any
@@ -142,10 +142,12 @@ class FLJobRuntime:
         model_bytes = self.spec.model_bytes
 
         def fold_pair(a, b):
-            # one fold into a fresh accumulator (the fold donates it);
+            # one served fold into a fresh accumulator holding ``a`` (the
+            # fold donates it): a 1-D leaf's one slab is the leaf itself;
             # returns the device array, so the probe waits for the fold
-            acc = FlatAcc(jnp.asarray(a), jax.tree.structure(b), (b.shape,))
-            return accumulate(acc, jnp.asarray(b), 1.0).flat
+            acc = SlabAcc((jnp.asarray(a),), jax.tree.structure(b),
+                          slab_plan((b.shape,)))
+            return accumulate(acc, jnp.asarray(b), 1.0).slabs[0]
 
         t_pair = measure_t_pair(
             fold_pair, min(model_bytes, 4 << 20),  # cap the probe size on CPU

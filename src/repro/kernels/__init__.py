@@ -1,8 +1,9 @@
 from repro.kernels.ops import (  # noqa: F401
-    FlatAcc,
+    SlabAcc,
     accumulate,
     fuse_quantized,
     fuse_updates,
     interpret_mode,
     quantize_update,
+    slab_plan,
 )

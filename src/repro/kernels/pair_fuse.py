@@ -2,15 +2,13 @@
 
     M1 (+) M2 = [f(M1[1], M2[1]), ..., f(M1[n], M2[n])]
 
-used by incremental (streaming / eager) aggregation, where updates are fused
-one pair at a time as they arrive. f is selected statically: mean, weighted
-sum, max, min. Elementwise and bandwidth-bound, over 1-D blocks; operands are
-upcast to fp32 in the kernel (a fold's bf16 update); weights sit in SMEM.
-
-With ``alias_a`` the output is written into ``a``'s buffer
-(``input_output_aliases``): a caller that donates ``a`` then folds in place,
-holding one accumulator, not two. A caller that does not donate ``a`` would
-pay XLA a copy of it, so the alias is off by default.
+for pairwise (incremental) aggregation, where updates are fused one pair at
+a time as they arrive. f is selected statically: mean, weighted sum, max,
+min. Elementwise and bandwidth-bound, over 1-D blocks; operands are upcast
+to fp32 in the kernel (a bf16 update); weights sit in SMEM. The served
+streaming fold does not call it (``kernels/ops.py`` folds by one XLA
+multiply-add per slab); ``autotune`` and ``chip_smoke.py`` time and check
+it.
 
 The block size ``bn`` is tunable (multiple of 1024 = 8*128 fp32 lanes);
 `repro.kernels.autotune` picks it per model size by minimising modeled HBM
@@ -50,7 +48,7 @@ def _make_kernel(op: str):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("op", "bn", "alias_a", "interpret"))
+                   static_argnames=("op", "bn", "interpret"))
 def pair_fuse(
     a: jax.Array,  # (N,)
     b: jax.Array,  # (N,)
@@ -59,7 +57,6 @@ def pair_fuse(
     wa: float = 0.5,
     wb: float = 0.5,
     bn: int = DEFAULT_BN,
-    alias_a: bool = False,
     interpret: bool,
 ) -> jax.Array:
     (n,) = a.shape
@@ -76,7 +73,6 @@ def pair_fuse(
         ],
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), a.dtype),
-        input_output_aliases={1: 0} if alias_a else {},
         interpret=interpret,
         name="pair_fuse",
     )(w, a, b)

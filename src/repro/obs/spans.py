@@ -14,11 +14,12 @@ The aggregator's served path opens three (``fl/aggregator.py``,
 
   repro.drain(round=r)         ``AggregationExecutor.drain``: poll, weight,
                                fold and commit
-  repro.fold(dtype=, nbytes=)  ``FusionState.fold``: one per update folded,
-                               around ``kernels.accumulate``'s dispatch of
+  repro.fold(dtype=, nbytes=,  ``FusionState.fold``: one per update folded,
+             slabs=, packed=)  around ``kernels.accumulate``'s dispatch of
                                one compiled program for the whole update;
-                               what it read, the staged update's dtype and
-                               the update's bytes, cached per layout
+                               what it read, the update's dtype and bytes,
+                               and the accumulator's buffers and the leaves
+                               packed into one of them, cached per layout
   repro.finish_round(round=r)  ``AggregationExecutor.finish_round``:
                                ``FusionState.finish`` (the mean and
                                ``apply``, one compiled program) and

@@ -133,14 +133,11 @@ def test_checkpoint_resume_roundtrip():
                                rtol=1e-5)
 
 
-# ---- the flat accumulator: one program per fold and per finish --------------
+# ---- the slab accumulator: one program per fold and per finish ---------------
 def _mixed_tree(seed, dtype):
     """A scalar, 1-D, 2-D and 4-D leaf in nested containers, 13,208
-    elements: more than one ``pair_fuse`` block, as every model is. (Where
-    the whole vector fits one block, XLA's CPU backend inlines the
-    interpreted kernel's single step into one fusion with the concat and
-    contracts ``a + w*u`` into a fused multiply-add, one rounding fewer;
-    the compiled TPU kernel rounds as written.)"""
+    elements: every leaf under ``PACK_BELOW``, so the accumulator is one
+    packed slab and the finish slices each leaf out of it."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     return {
         "scale": jax.random.normal(ks[0], ()).astype(dtype),
@@ -150,17 +147,22 @@ def _mixed_tree(seed, dtype):
     }
 
 
+#: one fold step of one leaf, compiled as the fold compiles it: a backend
+#: that contracts ``a + w*u`` into a fused multiply-add (XLA's CPU backend
+#: does) then contracts it on both sides
+_fold_step = jax.jit(lambda a, u, w: a + w * u.astype(jnp.float32))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flat_fold_matches_per_leaf_arithmetic(dtype):
     """Each element sees the per-leaf operations in the same order — ``w*u``
-    first, then ``1.0*a + w*u``, then ``/ tw`` — so the flat fold equals
-    them exactly, and the mean comes back in the update's tree."""
+    first, then ``a + w*u``, then ``/ tw`` — so the slab fold equals them
+    exactly, and the mean comes back in the update's tree."""
     ups = [_mixed_tree(s, dtype) for s in range(4)]
     ws = [3.0, 0.7, 12.0, 1.5]
     want = jax.tree.map(lambda u: u.astype(jnp.float32) * ws[0], ups[0])
     for u, w in zip(ups[1:], ws[1:]):
-        want = jax.tree.map(
-            lambda a, x, w=w: 1.0 * a + w * x.astype(jnp.float32), want, u)
+        want = jax.tree.map(lambda a, x, w=w: _fold_step(a, x, w), want, u)
     tw = sum(ws)
     want = jax.tree.map(lambda a: a / tw, want)
 
@@ -233,22 +235,36 @@ def _count_primitive(jaxpr, name) -> int:
 
 
 def test_fold_is_one_program_with_one_kernel():
-    """A fold of the whole tree is one compiled program holding exactly one
-    pallas_call; a finish is one program too."""
-    from repro.kernels import FlatAcc, accumulate
+    """A fold of the whole tree is one compiled program, with no Pallas
+    kernel in it, whose every output is written into a donated buffer of
+    the accumulator; a finish is one program too."""
+    from repro.kernels import SlabAcc, accumulate
+    from repro.kernels.ops import fold_into
 
-    st_ = FusionState().fold(_mixed_tree(0, jnp.float32), 2.0)
-    acc, update = st_.acc, _mixed_tree(1, jnp.bfloat16)
+    def tree(seed, dtype):  # one leaf a slab of its own, four packed
+        wide = jax.random.normal(jax.random.PRNGKey(seed), (256, 300))
+        return {**_mixed_tree(seed, dtype), "wide": wide.astype(dtype)}
+
+    st_ = FusionState().fold(tree(0, jnp.float32), 2.0)
+    acc, update = st_.acc, tree(1, jnp.bfloat16)
+    as_acc = lambda slabs: SlabAcc(slabs, acc.treedef, acc.plan)  # noqa: E731
     fold = jax.make_jaxpr(
-        lambda flat, u: accumulate(FlatAcc(flat, acc.treedef, acc.shapes),
-                                   u, 0.5).flat)(acc.flat, update)
-    assert len(fold.jaxpr.eqns) == 1
-    assert _count_primitive(fold.jaxpr, "pallas_call") == 1
+        lambda slabs, u: accumulate(as_acc(slabs), u, 0.5).slabs)(
+            acc.slabs, update)
+    (program,) = fold.jaxpr.eqns
+    assert program.params["name"] == "fold_into"
+    assert _count_primitive(fold.jaxpr, "pallas_call") == 0
+    n_out = len(program.outvars)
+    assert n_out == acc.plan.n_slabs == 2
+    assert program.params["donated_invars"] == (
+        (True,) * n_out + (False,) * (len(program.invars) - n_out))
+    lowered = fold_into.lower(acc, jax.tree.leaves(update), np.float32(0.5))
+    assert lowered.as_text().count("tf.aliasing_output") == n_out
 
-    model = _mixed_tree(2, jnp.float32)
+    model = tree(2, jnp.float32)
     finish = jax.make_jaxpr(
-        lambda flat: FusionState(FlatAcc(flat, acc.treedef, acc.shapes),
-                                 2.0).finish(FedSGD(), model))(acc.flat)
+        lambda slabs: FusionState(as_acc(slabs), 2.0).finish(FedSGD(), model)
+    )(acc.slabs)
     assert len(finish.jaxpr.eqns) == 1
     assert _count_primitive(finish.jaxpr, "pallas_call") == 0
 

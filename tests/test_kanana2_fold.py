@@ -20,7 +20,8 @@ import pytest
 
 from repro.core.queue import MessageQueue
 from repro.fl.aggregator import AggregationExecutor
-from repro.kernels.ops import first_fold, fold_into
+from repro.kernels.ops import (PACK_BELOW, SlabAcc, first_fold, fold_into,
+                               slab_plan)
 
 JOB = "kanana"
 D, HEADS, KV_LORA, NOPE, ROPE, V = 64, 4, 16, 16, 8, 16
@@ -179,44 +180,51 @@ def _eqns(jaxpr):
                     yield from _eqns(j)
 
 
-def _staging(eqns, n):
-    """The concats that stage an update of ``n`` elements."""
-    return [e for e in eqns if e.primitive.name == "concatenate"
-            and e.outvars[0].aval.shape == (n,)]
-
-
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_fold_stages_the_update_at_its_own_width(dtype):
-    """A bf16 update is concatenated as bf16 and read so by pair_fuse, and
-    the first fold multiplies in fp32. An fp32 update's fold is the one
-    fp32 concat and pair_fuse, with no cast."""
-    leaves = jax.tree.leaves(tree(0, dtype))
-    n = sum(math.prod(x.shape) for x in leaves)
-    acc = jnp.zeros((n,), jnp.float32)
-    fold = jax.make_jaxpr(lambda a, u, w: fold_into(a, u, w, interpret=True))(
-        acc, leaves, jnp.ones((2,), jnp.float32))
-    eqns = list(_eqns(fold.jaxpr))
-    (concat,) = _staging(eqns, n)
-    assert concat.outvars[0].aval.dtype == dtype
-    (kernel,) = [e for e in eqns if e.primitive.name == "pallas_call"]
-    assert [v.aval.dtype for v in kernel.invars[1:]] == [jnp.float32, dtype]
-    assert kernel.outvars[0].aval.dtype == jnp.float32
-    assert not [e for e in eqns if e.primitive.name == "convert_element_type"]
+    """The fold reads a bf16 update as bf16: the packed slab is
+    concatenated in bf16 from exactly the leaves under ``PACK_BELOW``, the
+    whole leaves are read where they lie, and each slab is upcast only
+    where its multiply-add reads it, so no fp32 copy of the update is
+    staged. The first fold multiplies in fp32. An fp32 update's folds have
+    no cast."""
+    # the layout's leaves and one as wide as a full-vocabulary head
+    wide = (0.05 * jax.random.normal(jax.random.PRNGKey(7), (VOCAB * 2, D * 8))
+            ).astype(dtype)
+    leaves = jax.tree.leaves(tree(0, dtype)) + [wide]
+    plan = slab_plan(tuple(x.shape for x in leaves))
+    small = [x for x in leaves if x.size < PACK_BELOW]
+    assert plan.whole == (len(leaves) - 1,) and len(small) == len(leaves) - 1
+    n_small = sum(x.size for x in small)
+    acc = SlabAcc((jnp.zeros(wide.shape), jnp.zeros((n_small,))),
+                  jax.tree.structure(leaves), plan)
 
-    first = jax.make_jaxpr(first_fold)(leaves, 2.0)
-    eqns = list(_eqns(first.jaxpr))
-    (concat,) = _staging(eqns, n)
-    assert concat.outvars[0].aval.dtype == dtype
-    (mul,) = [e for e in eqns if e.primitive.name == "mul"]
-    assert [v.aval.dtype for v in mul.invars] == [jnp.float32] * 2
-    upcasts = [e for e in eqns if e.primitive.name == "convert_element_type"
-               and e.invars[0] is concat.outvars[0]]
-    assert len(upcasts) == (dtype != jnp.float32)
+    def read_at_own_width(eqns, n_slabs):
+        (concat,) = [e for e in eqns if e.primitive.name == "concatenate"]
+        assert concat.outvars[0].aval.shape == (n_small,)
+        assert concat.outvars[0].aval.dtype == dtype
+        assert [v.aval.size for v in concat.invars] == [x.size for x in small]
+        assert all(v.aval.dtype == dtype for v in concat.invars)
+        upcasts = [e for e in eqns if e.primitive.name == "convert_element_type"]
+        assert len(upcasts) == n_slabs * (dtype != jnp.float32)
+        for up in upcasts:
+            assert up.invars[0].aval.dtype == dtype
+            (reader,) = [e for e in eqns if up.outvars[0] in e.invars]
+            assert reader.primitive.name == "mul"
+        muls = [e for e in eqns if e.primitive.name == "mul"]
+        assert len(muls) == n_slabs
+        assert all(v.aval.dtype == jnp.float32 for e in muls for v in e.invars)
+
+    fold = jax.make_jaxpr(fold_into)(acc, leaves, np.float32(1.0))
+    read_at_own_width(list(_eqns(fold.jaxpr)), 2)
+    first = jax.make_jaxpr(first_fold)(leaves, np.float32(2.0))
+    read_at_own_width(list(_eqns(first.jaxpr)), 2)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_fold_span_says_what_the_fold_read(tmp_path, dtype):
-    """``repro.fold`` carries the staged dtype and the update's bytes."""
+    """``repro.fold`` carries the update's dtype and bytes, and the
+    accumulator's slabs and packed leaves."""
     updates = [tree(i, dtype) for i in range(2)]
     jax.block_until_ready(serve(updates, [1, 2], tree(99, dtype)))  # warm
     jax.profiler.start_trace(str(tmp_path))
@@ -231,4 +239,7 @@ def test_fold_span_says_what_the_fold_read(tmp_path, dtype):
     nbytes = sum(x.nbytes for x in jax.tree.leaves(updates[0]))
     assert nbytes == sum(math.prod(s) for _, s, _ in layout()) * (
         jnp.dtype(dtype).itemsize)
-    assert folds == [{"dtype": jnp.dtype(dtype).name, "nbytes": nbytes}] * 2
+    plan = slab_plan(tuple(s for _, s, _ in layout()))
+    assert folds == [{"dtype": jnp.dtype(dtype).name, "nbytes": nbytes,
+                      "slabs": plan.n_slabs,
+                      "packed": len(plan.packed)}] * 2

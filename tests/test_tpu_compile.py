@@ -6,9 +6,9 @@ topology, so these tests refuse what the chip's compiler would refuse
 (unaligned blocks, unsupported in-kernel ops, too much VMEM or HBM) at no
 chip time. Sizes: the paper's §6.3 update sizes (`benchmarks/workloads.py`)
 at the tiles `repro.kernels.autotune` picks, and every autotuned row of
-`benchmarks/kernel_baseline.json`. Each compiled program must contain the
-Pallas kernel as a `tpu_custom_call`, i.e. nothing fell back to the
-interpreter.
+`benchmarks/kernel_baseline.json`. Each compiled kernel program must
+contain the Pallas kernel as a `tpu_custom_call`, i.e. nothing fell back to
+the interpreter; the streaming fold holds no kernel.
 
 The topology is described only inside the module fixture: describing it
 loads the TPU library, which one process at a time may hold.
@@ -23,7 +23,7 @@ import pytest
 
 from benchmarks.workloads import WORKLOADS
 from repro.fl.fusion import finished_model, get_algorithm
-from repro.kernels import FlatAcc
+from repro.kernels import SlabAcc, slab_plan
 from repro.kernels import autotune as at
 from repro.kernels.ops import first_fold, fold_into
 from repro.kernels.fused_agg import fused_agg
@@ -101,7 +101,7 @@ def test_kernel_compiles_at_baseline_tile(one_chip, row, tile):
 
 def test_bf16_updates_compile(one_chip):
     """Model updates arrive in the model's dtype (bf16): the batch drain
-    upcasts in the kernel and the fold upcasts before pair_fuse."""
+    upcasts in the kernel."""
     n = 1 << 22
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     tile = at.autotune("fused_agg", K_FUSED, n)
@@ -126,9 +126,10 @@ def test_kernel_compiles_for_short_leaves(one_chip, kernel, k, n):
 def test_fold_and_finish_compile_at_benchmark_leaves(one_chip, config):
     """The served path's three programs at the leaf lists of
     ``chipbench/configs`` (32, 711 and 73 leaves; the last bf16): the first
-    fold, a fold (one pair_fuse, written in place into the donated fp32
-    accumulator) and a round's finish, which publishes the model in its own
-    dtype."""
+    fold, a fold (one multiply-add per slab, no kernel, every output
+    written into the donated fp32 accumulator, no staging buffer beyond
+    the packed slab's leaves) and a round's finish, which publishes the
+    model in its own dtype."""
     cfg = json.loads((_ROOT / "chipbench" / "configs" /
                       f"{config}.json").read_text())
     f32 = jnp.float32
@@ -139,22 +140,27 @@ def test_fold_and_finish_compile_at_benchmark_leaves(one_chip, config):
     leaves = [arg(s, dtype) for s in shapes]
     n = sum(math.prod(s) for s in shapes)
     assert n == cfg["n_params"]
-    acc = arg((n,))
+    plan = slab_plan(shapes)
+    n_packed = sum(math.prod(shapes[i]) for i in plan.packed)
 
     first = first_fold.lower(leaves, arg(())).compile()
     assert first.memory_analysis().output_size_in_bytes >= 4 * n
+    slabs = [arg(s.shape) for s in jax.eval_shape(first_fold, leaves,
+                                                  arg(()))]
+    assert len(slabs) == plan.n_slabs
 
-    fold = fold_into.lower(acc, leaves, arg((2,)), interpret=False).compile()
-    assert fold.as_text().count("tpu_custom_call") >= 1
+    acc = SlabAcc(slabs, jax.tree.structure(leaves), plan)
+    fold = fold_into.lower(acc, leaves, arg(())).compile()
+    assert "tpu_custom_call" not in fold.as_text()
+    # every output is one of the donated accumulator's buffers
+    header = fold.as_text().split("\n", 1)[0]
+    assert header.count("-alias)") == plan.n_slabs
     mem = fold.memory_analysis()
-    # the whole output is the donated accumulator's buffer
-    assert mem.alias_size_in_bytes == mem.output_size_in_bytes >= 4 * n
-    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            < HBM_BYTES)
+    assert mem.alias_size_in_bytes >= 4 * n
+    assert mem.temp_size_in_bytes <= 4 * n_packed
 
-    flat = FlatAcc(acc, jax.tree.structure(leaves), shapes)
     finish = finished_model.lower(
-        get_algorithm(cfg["algorithm"]), flat, arg(()), leaves,
+        get_algorithm(cfg["algorithm"]), acc, arg(()), leaves,
         arg(())).compile()
     mem = finish.memory_analysis()
     assert mem.output_size_in_bytes >= dtype.itemsize * n

@@ -1,9 +1,9 @@
 """Real-training strategy ablation: train ONE real federated job (JAX
-parties + Pallas fusion kernels), then replay its measured per-party
+parties + the aggregator's streaming fold), then replay its measured per-party
 arrivals under every registered deployment strategy — the real-training
 analogue of jit_ablation. All strategies are priced from identical initial
-estimator state (the pre-calibration t_pair measured on the actual fusion
-kernel) and the single-worker streaming fuse cost, so the container-second
+estimator state (the pre-calibration t_pair measured on the served
+fold) and the single-worker streaming fuse cost, so the container-second
 and latency columns are directly comparable; the §6 headline (JIT
 container-seconds <= always-on) falls out of one shared training run.
 

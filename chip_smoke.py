@@ -181,7 +181,7 @@ def check_resume(result, uninterrupted) -> dict:
 # ---- phase c --------------------------------------------------------------
 def check_drains(result, k: int = K_DRAIN) -> dict:
     """Phase c: batch and int8 drains of K retained updates vs the oracles."""
-    from repro.kernels import fuse_quantized, quantize_update, ref
+    from repro.kernels import fuse_quantized, fuse_updates, quantize_update, ref
 
     alg = result.runtime.agg.alg
     updates, n_ex = _updates(result.runtime)
@@ -191,7 +191,7 @@ def check_drains(result, k: int = K_DRAIN) -> dict:
     w = jnp.asarray(ws, jnp.float32)
     stack = lambda leaves: jnp.stack([x.reshape(-1) for x in leaves])
 
-    batch = alg.fuse(updates, n_ex)
+    batch = fuse_updates(updates, ws)
     want = [ref.fused_agg_ref(stack([jax.tree.leaves(u)[i] for u in updates]),
                               w) for i in range(len(jax.tree.leaves(batch)))]
     got = [x.reshape(-1) for x in jax.tree.leaves(batch)]

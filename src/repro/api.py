@@ -32,7 +32,7 @@ facade:
     svc.advance(until=3600.0); windows = svc.poll()   # mid-run metrics
     report = svc.drain()                              # -> OnlineReport
 
-    # 3. real-JAX federated training (parties + Pallas fusion kernels),
+    # 3. real-JAX federated training (parties + the streaming fold),
     #    priced under ANY registered strategy via the measured-arrival replay
     result = platform.train(model_cfg, job)             # -> TrainingResult
     ao = replay_measured(job, result.runtime.measured_rounds, "eager_ao")
@@ -386,7 +386,7 @@ class Platform:
         verbose: bool = False,
         **runtime_kw,
     ) -> TrainingResult:
-        """Run real federated training (JAX parties + Pallas fusion kernels)
+        """Run real federated training (JAX parties + the streaming fold)
         for `job` on `model_cfg`, priced under `policy`'s deployment
         strategy on a virtual clock driven by the measured arrivals.
 

@@ -1,6 +1,6 @@
 """Aggregation executor: consumes model updates from the message queue,
-folds them into a (checkpointable, mergeable) FusionState using the Pallas
-fusion kernels, and produces the fused global model.
+folds them into a (checkpointable, mergeable) FusionState by the streaming
+fold of ``repro.fl.fusion``, and produces the fused global model.
 
 Supports the three behaviours JIT scheduling needs:
   * incremental folding (updates fused as they arrive — streaming container)

@@ -1,5 +1,5 @@
-"""End-to-end FL job runtime: REAL JAX local training at the parties, real
-kernel-based fusion at the aggregator, and a scheduling timeline evaluated
+"""End-to-end FL job runtime: REAL JAX local training at the parties, the
+served streaming fold at the aggregator, and a scheduling timeline evaluated
 on a virtual clock driven by the measured training times.
 
 This is the bridge between the paper's two halves: learning fidelity (does
@@ -38,8 +38,8 @@ from repro.core.strategies import MeasuredArrivals, RoundEngine
 from repro.data.partition import dirichlet_domain_mixes, party_sizes
 from repro.data.synthetic import SyntheticLM, SyntheticLMConfig
 from repro.fl.aggregator import AggregationExecutor
+from repro.fl.fusion import SlabAcc, accumulate, slab_plan
 from repro.fl.party import Party
-from repro.kernels import SlabAcc, accumulate, slab_plan
 from repro.models import model as M
 
 Pytree = Any
@@ -138,7 +138,7 @@ class FLJobRuntime:
         self.measured_rounds: List[Dict[str, Tuple[float, float]]] = []
 
     def _make_estimator(self) -> AggregationEstimator:
-        """Offline t_pair measurement on the actual fusion kernel (§5.4)."""
+        """Offline t_pair measurement on the served fold (§5.4)."""
         model_bytes = self.spec.model_bytes
 
         def fold_pair(a, b):

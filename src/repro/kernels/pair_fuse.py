@@ -6,9 +6,9 @@ for pairwise (incremental) aggregation, where updates are fused one pair at
 a time as they arrive. f is selected statically: mean, weighted sum, max,
 min. Elementwise and bandwidth-bound, over 1-D blocks; operands are upcast
 to fp32 in the kernel (a bf16 update); weights sit in SMEM. The served
-streaming fold does not call it (``kernels/ops.py`` folds by one XLA
-multiply-add per slab); ``autotune`` and ``chip_smoke.py`` time and check
-it.
+streaming fold does not call it (``repro.fl.fusion.accumulate`` folds by
+one XLA multiply-add per slab); ``autotune`` and ``chip_smoke.py`` time and
+check it.
 
 The block size ``bn`` is tunable (multiple of 1024 = 8*128 fp32 lanes);
 `repro.kernels.autotune` picks it per model size by minimising modeled HBM
@@ -25,7 +25,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BN = 8 * 1024
-BN = DEFAULT_BN  # backwards-compatible alias
 
 
 def _make_kernel(op: str):

@@ -15,7 +15,7 @@ The aggregator's served path opens three (``fl/aggregator.py``,
   repro.drain(round=r)         ``AggregationExecutor.drain``: poll, weight,
                                fold and commit
   repro.fold(dtype=, nbytes=,  ``FusionState.fold``: one per update folded,
-             slabs=, packed=)  around ``kernels.accumulate``'s dispatch of
+             slabs=, packed=)  around ``accumulate``'s dispatch of
                                one compiled program for the whole update;
                                what it read, the update's dtype and bytes,
                                and the accumulator's buffers and the leaves

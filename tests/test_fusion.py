@@ -1,13 +1,21 @@
 """Fusion algorithms: closed-form equivalence, and the LINEARITY properties
 JIT aggregation exploits — incremental == batch, order-independence,
 partial-merge (parallel aggregation) == sequential."""
+import os
+import pathlib
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hyp import given, settings, st  # optional hypothesis (requirements-dev.txt)
 
+from repro.fl.aggregator import AggregationExecutor
 from repro.fl.fusion import FedAvg, FedProx, FedSGD, FusionState, get_algorithm
+
+_REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _updates(k=4, seed=0, shapes=((8, 4), (16,), (2, 3, 5))):
@@ -32,7 +40,7 @@ def test_fedavg_weighted_mean_closed_form():
     ups = _updates(4)
     n_ex = [10, 20, 30, 40]
     alg = FedAvg()
-    fused = alg.fuse(ups, n_ex)
+    fused = AggregationExecutor("job", alg).aggregate(ups, n_ex)
     want = _closed_form(ups, [float(n) for n in n_ex])
     for k in fused:
         np.testing.assert_allclose(fused[k], want[k], rtol=2e-5, atol=2e-5)
@@ -42,7 +50,7 @@ def test_fedsgd_applies_gradient_step():
     model = {"w": jnp.ones((4, 4))}
     grads = [{"w": jnp.full((4, 4), 2.0)}, {"w": jnp.full((4, 4), 4.0)}]
     alg = FedSGD()
-    fused = alg.fuse(grads, [1, 1])
+    fused = AggregationExecutor("job", alg).aggregate(grads, [1, 1])
     new = alg.apply(model, fused, lr=0.1)
     np.testing.assert_allclose(new["w"], 1.0 - 0.1 * 3.0, rtol=1e-6)
 
@@ -50,8 +58,8 @@ def test_fedsgd_applies_gradient_step():
 def test_fedprox_server_side_equals_fedavg():
     ups = _updates(3)
     n_ex = [5, 5, 10]
-    a = FedAvg().fuse(ups, n_ex)
-    b = FedProx().fuse(ups, n_ex)
+    a = AggregationExecutor("job", FedAvg()).aggregate(ups, n_ex)
+    b = AggregationExecutor("job", FedProx()).aggregate(ups, n_ex)
     for k in a:
         np.testing.assert_allclose(a[k], b[k], rtol=1e-6)
 
@@ -238,8 +246,7 @@ def test_fold_is_one_program_with_one_kernel():
     """A fold of the whole tree is one compiled program, with no Pallas
     kernel in it, whose every output is written into a donated buffer of
     the accumulator; a finish is one program too."""
-    from repro.kernels import SlabAcc, accumulate
-    from repro.kernels.ops import fold_into
+    from repro.fl.fusion import SlabAcc, accumulate, fold_into
 
     def tree(seed, dtype):  # one leaf a slab of its own, four packed
         wide = jax.random.normal(jax.random.PRNGKey(seed), (256, 300))
@@ -289,3 +296,16 @@ def test_fold_and_merge_spend_the_state_they_consume():
         merged.result()["w0"],
         (ups[0]["w0"] + 2 * ups[1]["w0"] + 4 * ups[2]["w0"]) / 7,
         rtol=1e-6, atol=1e-6)
+
+
+def test_aggregator_imports_no_pallas():
+    """The aggregator owns its fold: importing it loads neither the Pallas
+    batch drains (``repro.kernels``) nor Pallas itself."""
+    code = ("import sys, repro.fl.aggregator; "
+            "print(sorted(m for m in sys.modules if m.startswith("
+            "('repro.kernels', 'jax.experimental.pallas'))))")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True, cwd=_REPO,
+                         env={**os.environ, "PYTHONPATH": str(_REPO / "src"),
+                              "JAX_PLATFORMS": "cpu"})
+    assert out.stdout.strip() == "[]", out.stdout

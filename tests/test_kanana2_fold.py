@@ -20,8 +20,8 @@ import pytest
 
 from repro.core.queue import MessageQueue
 from repro.fl.aggregator import AggregationExecutor
-from repro.kernels.ops import (PACK_BELOW, SlabAcc, first_fold, fold_into,
-                               slab_plan)
+from repro.fl.fusion import (PACK_BELOW, SlabAcc, first_fold, fold_into,
+                             slab_plan)
 
 JOB = "kanana"
 D, HEADS, KV_LORA, NOPE, ROPE, V = 64, 4, 16, 16, 8, 16

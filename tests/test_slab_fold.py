@@ -21,8 +21,7 @@ import pytest
 
 from repro.core.queue import MessageQueue
 from repro.fl.aggregator import AggregationExecutor
-from repro.fl.fusion import FusionState, get_algorithm
-from repro.kernels.ops import PACK_BELOW, slab_plan
+from repro.fl.fusion import PACK_BELOW, FusionState, get_algorithm, slab_plan
 
 _CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "chipbench" / "configs"
 JOB = "slabs"

@@ -22,10 +22,9 @@ import jax.numpy as jnp
 import pytest
 
 from benchmarks.workloads import WORKLOADS
-from repro.fl.fusion import finished_model, get_algorithm
-from repro.kernels import SlabAcc, slab_plan
+from repro.fl.fusion import (SlabAcc, finished_model, first_fold, fold_into,
+                             get_algorithm, slab_plan)
 from repro.kernels import autotune as at
-from repro.kernels.ops import first_fold, fold_into
 from repro.kernels.fused_agg import fused_agg
 from repro.kernels.pair_fuse import pair_fuse
 from repro.kernels.quant_agg import quant_agg
